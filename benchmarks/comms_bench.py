@@ -49,7 +49,6 @@ def comms_record() -> dict:
     import numpy as np
 
     from repro.analysis.collectives import collective_stats
-    from repro.common.compat import use_mesh
     from repro.common.quant import rel_ulp_error, wa_dtype
     from repro.configs import get_smoke_config
     from repro.core.hwa import HWAConfig
@@ -92,7 +91,7 @@ def comms_record() -> dict:
 
         win = window_state_args(sync)
         n_buf = len(win) - 3
-        with use_mesh(mesh):
+        with mesh:
             out = compiled(jax.tree.map(jnp.asarray, div), *win)
         wa = jax.tree.map(lambda x: np.asarray(x), out[3 + n_buf])
 
@@ -127,8 +126,6 @@ def main(print_fn=print):
     rec = run_forced_device_worker(__file__, _WORKER_FLAG,
                                    error_row="sync/comms/ERROR",
                                    print_fn=print_fn)
-    if not rec:
-        return {}
     for tok in TOKENS:
         r = rec[tok]
         print_fn(csv_row(

@@ -189,10 +189,9 @@ def _mesh_sync_worker():
     import jax
 
     # The gated leg deliberately builds the legacy GSPMD fallback, which
-    # is a hard error on multi-device CPU meshes (launch/sync/legacy.py —
-    # XLA 0.4.37 miscompiles the assembly). This worker only introspects
-    # the lowered HLO and never trusts computed values, so opt into the
-    # escape hatch.
+    # is a hard error on multi-device CPU meshes (launch/sync/legacy.py).
+    # This worker only introspects the lowered HLO and never trusts
+    # computed values, so opt into the escape hatch.
     os.environ.setdefault("REPRO_ALLOW_LEGACY_ASSEMBLY", "1")
 
     from repro.configs import get_smoke_config
@@ -244,8 +243,6 @@ def gated_vs_mesh_resident(print_fn=print):
     rec = run_forced_device_worker(__file__, _WORKER_FLAG,
                                    error_row="kernel/mesh_sync/ERROR",
                                    print_fn=print_fn)
-    if not rec:
-        return {}
     for name in ("gated", "mesh_resident", "fsdp_grouped"):
         r = rec[name]
         print_fn(csv_row(

@@ -79,8 +79,6 @@ def main(print_fn=print):
     rec = run_forced_device_worker(__file__, _WORKER_FLAG,
                                    error_row="mesh_comm/ERROR",
                                    print_fn=print_fn)
-    if not rec:
-        return {}
     mesh_n, mesh_b = rec["mesh_train"]
     vmap_n, vmap_b = rec["vmap_train"]
     sync_n, sync_b = rec["sync"]

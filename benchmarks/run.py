@@ -14,6 +14,8 @@ import os
 import sys
 import time
 
+from repro.common.compile_cache import use_compile_cache
+
 BENCHES = [
     ("table2", "benchmarks.table2_methods"),
     ("table3", "benchmarks.table3_ablation"),
@@ -70,8 +72,10 @@ def main() -> None:
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
     os.makedirs("experiments/bench", exist_ok=True)
+    use_compile_cache()
     print("name,us_per_call,derived")
     rows = []
+    failed = []
 
     def sink(line):
         print(line, flush=True)
@@ -84,8 +88,9 @@ def main() -> None:
         mod = __import__(module, fromlist=["main"])
         try:
             result = mod.main(print_fn=sink)
-        except Exception as e:  # noqa: BLE001 — report and continue
+        except Exception as e:  # noqa: BLE001 — report, go on, fail at end
             result = None
+            failed.append(name)
             sink(f"{name}/ERROR,0,{type(e).__name__}: {e}")
         sink(f"{name}/wall_s,{(time.time()-t0)*1e6:.0f},done")
         if name in _BENCH_JSON_KEY and isinstance(result, dict) and result:
@@ -93,6 +98,8 @@ def main() -> None:
     with open("experiments/bench/rows.csv", "w") as f:
         f.write("name,us_per_call,derived\n")
         f.write("\n".join(rows) + "\n")
+    if failed:
+        sys.exit(f"benchmarks failed: {', '.join(failed)}")
 
 
 if __name__ == '__main__':

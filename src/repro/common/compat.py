@@ -1,111 +1,54 @@
-"""JAX cross-version compatibility shims.
+"""The repo's one entry point for JAX mesh and tree APIs.
 
-Compat policy (the repo's one rule for version drift): **every call into a
-JAX API that moved, was renamed, or grew a replacement between 0.4.x and
-≥0.5 goes through this module** — never a direct ``jax.<new_api>`` call
-with a local try/except at the call site. Each shim prefers the newest
-public API when present and falls back to the oldest one the pinned
-container (jax 0.4.37) ships, so the same source runs unmodified on both.
-Shims are plain functions/objects resolved at import time where possible
-(zero per-call overhead) and covered by ``tests/test_compat.py``, which
-monkeypatches both branches.
+Every caller reaches ``shard_map``, mesh construction and path-aware tree
+maps through this module, so a future API move is a one-file change.
+The repo runs one JAX generation (0.9); there are no version fallbacks.
 
-Currently papered-over drift:
-
-- ``jax.tree.flatten_with_path`` / ``jax.tree.map_with_path`` (≥0.5 /
-  late 0.4): fall back to ``jax.tree_util.tree_flatten_with_path`` /
-  ``tree_map_with_path`` (present since 0.4.6).
-- ``jax.set_mesh`` (≥0.6) / ``jax.sharding.use_mesh`` (0.5.x): fall back
-  to the ``Mesh`` context manager (``with mesh:``), which all 0.4.x
-  releases support.
-- ``jax.make_mesh`` (≥0.4.34): fall back to
-  ``mesh_utils.create_device_mesh`` + ``jax.sharding.Mesh``.
-- ``jax.shard_map`` (≥0.8, experimental graduation): fall back to
-  ``jax.experimental.shard_map.shard_map``.
+- ``shard_map`` keeps the repo's ``auto=`` spelling for partial-manual
+  maps: the axes NOT listed in ``auto`` are manual, which is what
+  ``jax.shard_map``'s ``axis_names=`` takes. ``check_rep`` is accepted as
+  an alias of ``check_vma``; both default to disabled.
 """
 from __future__ import annotations
 
-import contextlib
-from typing import Any
-
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["tree_flatten_with_path", "tree_map_with_path", "use_mesh",
-           "make_mesh", "shard_map"]
+__all__ = ["tree_flatten_with_path", "make_mesh", "shard_map"]
 
-
-# ------------------------------------------------------------ pytree paths
-
-if hasattr(jax.tree, "flatten_with_path"):          # jax ≥ 0.5
-    tree_flatten_with_path = jax.tree.flatten_with_path
-else:                                               # jax 0.4.x
-    tree_flatten_with_path = jax.tree_util.tree_flatten_with_path
-
-if hasattr(jax.tree, "map_with_path"):
-    tree_map_with_path = jax.tree.map_with_path
-else:
-    tree_map_with_path = jax.tree_util.tree_map_with_path
-
-
-# ------------------------------------------------------------------- mesh
-
-def use_mesh(mesh) -> contextlib.AbstractContextManager:
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    ``jax.set_mesh`` (≥0.6) → ``jax.sharding.use_mesh`` (0.5.x) → the
-    ``Mesh`` object's own context manager (0.4.x).
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh
+tree_flatten_with_path = jax.tree.flatten_with_path
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with a pre-0.4.34 fallback via mesh_utils."""
-    if devices is None and hasattr(jax, "make_mesh"):
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
-    from jax.experimental import mesh_utils
-    devs = mesh_utils.create_device_mesh(tuple(axis_shapes), devices=devices)
-    return jax.sharding.Mesh(devs, tuple(axis_names))
+    """``jax.make_mesh`` over ``devices`` (default: all local devices).
 
-
-# -------------------------------------------------------------- shard_map
-
-def _resolve_shard_map():
-    if hasattr(jax, "shard_map"):                   # jax ≥ 0.8
-        return jax.shard_map
-    from jax.experimental.shard_map import shard_map as _sm  # 0.4.x–0.7
-    return _sm
+    Every axis is ``Auto``: layouts come from the jit in/out shardings
+    and the partitioner, as the repo's step bundles expect. Drive a
+    compiled step inside the mesh's own ``with mesh:`` block, not
+    ``jax.set_mesh``: the latter commits every array created inside it
+    to the mesh, replicated, and a compiled step refuses committed
+    arguments whose sharding differs from its declared one."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
 def shard_map(f, mesh, *, in_specs, out_specs, auto=frozenset(),
               check_rep=None, check_vma=None):
-    """``shard_map`` across the keyword drift.
+    """``jax.shard_map`` with every mesh axis outside ``auto`` manual.
 
-    0.4.x–0.7 take ``check_rep``/``auto`` keywords; ≥0.8 renamed
-    ``check_rep`` to ``check_vma`` and replaced ``auto`` with mesh
-    ``axis_types``. Callers may pass either replication-check spelling;
-    both default to disabled. We try the old keywords first and degrade to
-    the new-style call on TypeError — on new versions the mesh built by
-    :func:`make_mesh` carries every axis as manual, which is only correct
-    for fully-manual maps, so callers that need partial-auto on ≥0.8
-    should migrate the mesh's axis_types (noted here so the failure mode
-    is a documented one, not a silent one).
+    An empty ``auto`` is a fully-manual map. A non-empty one leaves those
+    axes to the partitioner (partial-auto), mapped onto ``axis_names``.
     """
-    check = check_rep if check_rep is not None else \
-        (check_vma if check_vma is not None else False)
-    sm = _resolve_shard_map()
-    try:
-        return sm(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check, auto=auto)
-    except TypeError:
-        if auto:
-            raise NotImplementedError(
-                "this jax's shard_map has no auto= keyword; dropping it "
-                "would silently turn a partial-auto map fully manual. "
-                "Migrate the mesh to axis_types-based auto axes "
-                "(see repro.common.compat docstring).")
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check)
+    check = check_rep if check_rep is not None else bool(check_vma)
+    unknown = set(auto) - set(mesh.axis_names)
+    if unknown:
+        raise ValueError(f"auto axes {sorted(unknown)} are not mesh axes "
+                         f"{mesh.axis_names}")
+    manual = frozenset(a for a in mesh.axis_names if a not in auto)
+    if not manual:
+        raise ValueError("shard_map needs at least one manual axis; "
+                         f"auto={sorted(auto)} covers the whole mesh")
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=manual,
+                         check_vma=check)

@@ -3,7 +3,7 @@
 The container is offline (no CIFAR/ImageNet), so the paper's claims are
 validated on tasks with a real train/test generalization gap:
 
-- ``make_markov_lm_dataset``: sequences from a fixed random 2nd-order
+- ``make_markov_lm_dataset``: sequences from a fixed random 1st-order
   Markov chain over the vocabulary. A model must learn the transition
   structure; a finite train set can be memorized, fresh test sequences
   cannot — so test loss measures generalization exactly as the paper's
@@ -42,20 +42,29 @@ class SyntheticDataset:
         return int(self.test_inputs.shape[0])
 
 
-def _sample_markov(key, trans, n_seq: int, seq_len: int) -> jax.Array:
-    """Sample ``n_seq`` sequences from a 1st-order chain ``trans``(v, v)."""
-    vocab = trans.shape[0]
+def _sample_markov(key, support, weights, n_seq: int, seq_len: int
+                   ) -> jax.Array:
+    """Sample ``n_seq`` sequences from a 1st-order chain whose row ``v``
+    moves to ``support[v, j]`` with probability ``weights[v, j]``."""
+    vocab = support.shape[0]
     k0, k1 = jax.random.split(key)
     first = jax.random.randint(k0, (n_seq,), 0, vocab)
-    logits = jnp.log(trans + 1e-9)
+    logits = jnp.log(weights + 1e-9)
 
     def step(prev, k):
-        nxt = jax.random.categorical(k, logits[prev])
+        nxt = support[prev, jax.random.categorical(k, logits[prev])]
         return nxt, nxt
 
     keys = jax.random.split(k1, seq_len - 1)
     _, rest = jax.lax.scan(step, first, keys)
     return jnp.concatenate([first[None], rest], axis=0).T  # (n_seq, seq_len)
+
+
+#: successors per token. A vocabulary up to this size gets a dense
+#: transition matrix; a larger one draws each row's support at random, so
+#: the chain costs O(vocab · MARKOV_FANOUT) memory (a dense (vocab, vocab)
+#: matrix at vocab 49,155 would be 9.7 GB of f32).
+MARKOV_FANOUT = 64
 
 
 def make_markov_lm_dataset(vocab: int = 256, seq_len: int = 128,
@@ -65,12 +74,18 @@ def make_markov_lm_dataset(vocab: int = 256, seq_len: int = 128,
     """LM dataset: inputs are tokens, targets are next tokens."""
     key = jax.random.key(seed)
     kt, ktr, kte = jax.random.split(key, 3)
-    # Sparse-ish random transition matrix: low concentration -> low entropy
+    # Sparse-ish random transition rows: low concentration -> low entropy
     # -> learnable structure with an achievable-but-nonzero loss floor.
-    alpha = jnp.full((vocab,), concentration)
-    trans = jax.random.dirichlet(kt, alpha, shape=(vocab,))
-    train = _sample_markov(ktr, trans, n_train, seq_len + 1)
-    test = _sample_markov(kte, trans, n_test, seq_len + 1)
+    fanout = min(vocab, MARKOV_FANOUT)
+    alpha = jnp.full((fanout,), concentration)
+    weights = jax.random.dirichlet(kt, alpha, shape=(vocab,))
+    if fanout == vocab:
+        support = jnp.broadcast_to(jnp.arange(vocab), (vocab, vocab))
+    else:
+        support = jax.random.randint(jax.random.fold_in(kt, 1),
+                                     (vocab, fanout), 0, vocab)
+    train = _sample_markov(ktr, support, weights, n_train, seq_len + 1)
+    test = _sample_markov(kte, support, weights, n_test, seq_len + 1)
     return SyntheticDataset(
         train_inputs=train[:, :-1], train_targets=train[:, 1:],
         test_inputs=test[:, :-1], test_targets=test[:, 1:], kind="lm")

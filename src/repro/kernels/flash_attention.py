@@ -1,4 +1,5 @@
-"""Pallas flash-attention kernels (TPU target, validated in interpret mode).
+"""Pallas flash-attention kernels (TPU target; numerics checked in interpret
+mode, Mosaic compile checked for v5e in tests/test_tpu_compile.py).
 
 Causal GQA attention with optional sliding window and logit softcap —
 the framework's perf-critical compute layer for training/prefill
@@ -32,10 +33,32 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-# jax 0.4.x names it TPUCompilerParams; newer jax renamed to
-# CompilerParams (same drift-shim spirit as repro.common.compat)
-CompilerParams = getattr(pltpu, "TPUCompilerParams",
-                         getattr(pltpu, "CompilerParams", None))
+
+def heads_flat(x):
+    """(B, S, H, D) -> (B, S, H·D), a free reshape. The kernels block one
+    head as a (rows, D) tile of the flattened lane axis, so the last two
+    block dims (rows, D) are tile-legal for Mosaic — a (…, 1, D) block
+    over the head axis is not (second-minor dim 1 of H)."""
+    B, S, H, D = x.shape
+    return x.reshape(B, S, H * D)
+
+
+def _eye(n: int):
+    return (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+            == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+
+
+def col_to_row(col):
+    """(n, 1) -> (1, n) without a relayout: mask the broadcast diagonal
+    and reduce over sublanes (exact — one nonzero term per column)."""
+    return jnp.sum(jnp.where(_eye(col.shape[0]), col, 0.0), axis=0,
+                   keepdims=True)
+
+
+def row_to_col(row):
+    """(1, n) -> (n, 1); the inverse of :func:`col_to_row`."""
+    return jnp.sum(jnp.where(_eye(row.shape[1]), row, 0.0), axis=1,
+                   keepdims=True)
 
 
 def block_live(q_start, k_start, block_q: int, block_k: int, causal: bool,
@@ -83,9 +106,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
     @pl.when(block_live(q_start, k_start, block_q, block_k, causal, window))
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)          # (bq, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)          # (bk, d)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0].astype(jnp.float32)                   # (bq, d)
+        k = k_ref[0].astype(jnp.float32)                   # (bk, d)
+        v = v_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * dscale
         if logit_softcap:
@@ -114,16 +137,15 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
     def _finalize():
         l = l_scr[...]
         safe = jnp.where(l > 0.0, l, 1.0)
-        o_ref[0, :, 0, :] = (acc_scr[...] / safe).astype(o_ref.dtype)
-        lse = jnp.where(l[:, 0] > 0.0,
-                        m_scr[:, 0] + jnp.log(safe[:, 0]), NEG_INF)
-        lse_ref[0, 0, :] = lse
+        o_ref[0] = (acc_scr[...] / safe).astype(o_ref.dtype)
+        lse = jnp.where(l > 0.0, m_scr[...] + jnp.log(safe), NEG_INF)
+        lse_ref[0, 0] = col_to_row(lse)
 
 
 def _flash_forward(q, k, v, causal, window, logit_softcap, block_q, block_k,
                    dscale, interpret):
     """Raw forward launch. Returns (out (B,S,Hq,D) q.dtype,
-    lse (B,Hq,S) f32) — lse is the backward's recompute residual."""
+    lse (B,Hq,1,S) f32) — lse is the backward's recompute residual."""
     B, S, Hq, D = q.shape
     T, Hkv = k.shape[1], k.shape[2]
     G = Hq // Hkv
@@ -133,34 +155,35 @@ def _flash_forward(q, k, v, causal, window, logit_softcap, block_q, block_k,
         _flash_kernel, block_q=block_q, block_k=block_k, causal=causal,
         window=window, logit_softcap=logit_softcap, dscale=dscale)
 
-    return pl.pallas_call(
+    out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, block_k, 1, D),
-                         lambda b, h, i, j: (b, j, h // G, 0)),
-            pl.BlockSpec((1, block_k, 1, D),
-                         lambda b, h, i, j: (b, j, h // G, 0)),
+            pl.BlockSpec((1, block_q, D), lambda b, h, i, j: (b, i, h)),
+            pl.BlockSpec((1, block_k, D), lambda b, h, i, j: (b, j, h // G)),
+            pl.BlockSpec((1, block_k, D), lambda b, h, i, j: (b, j, h // G)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, i, j: (b, h, i)),
+            pl.BlockSpec((1, block_q, D), lambda b, h, i, j: (b, i, h)),
+            # per-row residuals (lse, Δ) are stored lane-dense as
+            # (B, Hq, 1, S): the last two block dims (1, block_q) are legal
+            pl.BlockSpec((1, 1, 1, block_q), lambda b, h, i, j: (b, h, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, S, Hq, D), q.dtype),
-            jax.ShapeDtypeStruct((B, Hq, S), jnp.float32),
+            jax.ShapeDtypeStruct((B, S, Hq * D), q.dtype),
+            jax.ShapeDtypeStruct((B, Hq, 1, S), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(q, k, v)
+    )(heads_flat(q), heads_flat(k), heads_flat(v))
+    return out.reshape(B, S, Hq, D), lse
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))

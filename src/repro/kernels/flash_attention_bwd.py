@@ -34,8 +34,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.flash_attention import (CompilerParams, NEG_INF,
-                                            band_mask, block_live)
+from repro.kernels.flash_attention import (NEG_INF, band_mask, block_live,
+                                            heads_flat, row_to_col)
 
 
 def _block_p_ds(q, kb, vb, do, lse, delta, q_start, k_start, *,
@@ -80,12 +80,12 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     @pl.when(block_live(q_start, k_start, block_q, block_k, causal, window))
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)          # (bq, d)
-        kb = k_ref[0, :, 0, :].astype(jnp.float32)         # (bk, d)
-        vb = v_ref[0, :, 0, :].astype(jnp.float32)
-        do = do_ref[0, :, 0, :].astype(jnp.float32)
-        lse = lse_ref[0, 0, :][:, None]                    # (bq, 1)
-        delta = delta_ref[0, 0, :][:, None]
+        q = q_ref[0].astype(jnp.float32)                   # (bq, d)
+        kb = k_ref[0].astype(jnp.float32)                  # (bk, d)
+        vb = v_ref[0].astype(jnp.float32)
+        do = do_ref[0].astype(jnp.float32)
+        lse = row_to_col(lse_ref[0, 0])                    # (bq, 1)
+        delta = row_to_col(delta_ref[0, 0])
         _, ds = _block_p_ds(
             q, kb, vb, do, lse, delta, q_start, k_start,
             block_q=block_q, block_k=block_k, causal=causal, window=window,
@@ -96,7 +96,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     @pl.when(j == nk - 1)
     def _finalize():
-        dq_ref[0, :, 0, :] = acc_scr[...].astype(dq_ref.dtype)
+        dq_ref[0] = acc_scr[...].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -117,15 +117,17 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(block_live(q_start, k_start, block_q, block_k, causal, window))
     def _compute():
-        kb = k_ref[0, :, 0, :].astype(jnp.float32)         # (bk, d)
-        vb = v_ref[0, :, 0, :].astype(jnp.float32)
+        kb = k_ref[0].astype(jnp.float32)                  # (bk, d)
+        vb = v_ref[0].astype(jnp.float32)
+        d = kb.shape[1]
         # GQA head-group accumulation: the G query heads sharing this kv
-        # head each contribute a (bq, bk) tile into the SAME dk/dv block
+        # head each contribute a (bq, bk) tile into the SAME dk/dv block;
+        # head g is lane slice [g·d, (g+1)·d) of the (bq, G·d) q/dO block
         for g in range(group):
-            q = q_ref[0, :, g, :].astype(jnp.float32)      # (bq, d)
-            do = do_ref[0, :, g, :].astype(jnp.float32)
-            lse = lse_ref[0, g, :][:, None]                # (bq, 1)
-            delta = delta_ref[0, g, :][:, None]
+            q = q_ref[0, :, g * d:(g + 1) * d].astype(jnp.float32)
+            do = do_ref[0, :, g * d:(g + 1) * d].astype(jnp.float32)
+            lse = row_to_col(lse_ref[0, g])                # (bq, 1)
+            delta = row_to_col(delta_ref[0, g])
             p, ds = _block_p_ds(
                 q, kb, vb, do, lse, delta, q_start, k_start,
                 block_q=block_q, block_k=block_k, causal=causal,
@@ -139,8 +141,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(i == nq - 1)
     def _finalize():
-        dk_ref[0, :, 0, :] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[0, :, 0, :] = dv_scr[...].astype(dv_ref.dtype)
+        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
 def flash_attention_bwd_pallas(q, k, v, out, lse, dout, *, causal: bool,
@@ -148,7 +150,7 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, dout, *, causal: bool,
                                block_q: int, block_k: int, dscale: float,
                                interpret: bool = True):
     """(dq, dk, dv) via the two recompute sweeps. Shapes as the forward;
-    lse is the forward's (B, Hq, S) f32 residual."""
+    lse is the forward's (B, Hq, 1, S) f32 residual."""
     B, S, Hq, D = q.shape
     T, Hkv = k.shape[1], k.shape[2]
     G = Hq // Hkv
@@ -156,7 +158,8 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, dout, *, causal: bool,
 
     # Δ_i = Σ_d dO·O per row — elementwise, stays in XLA (not a launch)
     delta = jnp.einsum("bshd,bshd->bhs", dout.astype(jnp.float32),
-                       out.astype(jnp.float32))
+                       out.astype(jnp.float32))[:, :, None, :]
+    qf, kf, vf, dof = (heads_flat(x) for x in (q, k, v, dout))
 
     common = dict(block_q=block_q, block_k=block_k, causal=causal,
                   window=window, logit_softcap=logit_softcap, dscale=dscale)
@@ -165,54 +168,51 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, dout, *, causal: bool,
         functools.partial(_dq_kernel, **common),
         grid=(B, Hq, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, block_k, 1, D),
-                         lambda b, h, i, j: (b, j, h // G, 0)),
-            pl.BlockSpec((1, block_k, 1, D),
-                         lambda b, h, i, j: (b, j, h // G, 0)),
-            pl.BlockSpec((1, block_q, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, i, j: (b, h, i)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, i, j: (b, h, i)),
+            pl.BlockSpec((1, block_q, D), lambda b, h, i, j: (b, i, h)),
+            pl.BlockSpec((1, block_k, D), lambda b, h, i, j: (b, j, h // G)),
+            pl.BlockSpec((1, block_k, D), lambda b, h, i, j: (b, j, h // G)),
+            pl.BlockSpec((1, block_q, D), lambda b, h, i, j: (b, i, h)),
+            pl.BlockSpec((1, 1, 1, block_q), lambda b, h, i, j: (b, h, 0, i)),
+            pl.BlockSpec((1, 1, 1, block_q), lambda b, h, i, j: (b, h, 0, i)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, D),
-                               lambda b, h, i, j: (b, i, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, S, Hq, D), q.dtype),
+        out_specs=pl.BlockSpec((1, block_q, D), lambda b, h, i, j: (b, i, h)),
+        out_shape=jax.ShapeDtypeStruct((B, S, Hq * D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(q, k, v, dout, lse, delta)
+    )(qf, kf, vf, dof, lse, delta)
 
-    # q/dO/lse/Δ arrive as whole GQA groups: block size G over the head
-    # dim at head-block index h covers query heads [h·G, (h+1)·G)
+    # q/dO/lse/Δ arrive as whole GQA groups: a G·D lane block (or G rows
+    # of lse/Δ) at head-block index h covers query heads [h·G, (h+1)·G)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, group=G, **common),
         grid=(B, Hkv, nk, nq),
         in_specs=[
-            pl.BlockSpec((1, block_q, G, D), lambda b, h, j, i: (b, i, h, 0)),
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, j, i: (b, j, h, 0)),
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, j, i: (b, j, h, 0)),
-            pl.BlockSpec((1, block_q, G, D), lambda b, h, j, i: (b, i, h, 0)),
-            pl.BlockSpec((1, G, block_q), lambda b, h, j, i: (b, h, i)),
-            pl.BlockSpec((1, G, block_q), lambda b, h, j, i: (b, h, i)),
+            pl.BlockSpec((1, block_q, G * D), lambda b, h, j, i: (b, i, h)),
+            pl.BlockSpec((1, block_k, D), lambda b, h, j, i: (b, j, h)),
+            pl.BlockSpec((1, block_k, D), lambda b, h, j, i: (b, j, h)),
+            pl.BlockSpec((1, block_q, G * D), lambda b, h, j, i: (b, i, h)),
+            pl.BlockSpec((1, G, 1, block_q), lambda b, h, j, i: (b, h, 0, i)),
+            pl.BlockSpec((1, G, 1, block_q), lambda b, h, j, i: (b, h, 0, i)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, j, i: (b, j, h, 0)),
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, j, i: (b, j, h, 0)),
+            pl.BlockSpec((1, block_k, D), lambda b, h, j, i: (b, j, h)),
+            pl.BlockSpec((1, block_k, D), lambda b, h, j, i: (b, j, h)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, T, Hkv, D), k.dtype),
-            jax.ShapeDtypeStruct((B, T, Hkv, D), v.dtype),
+            jax.ShapeDtypeStruct((B, T, Hkv * D), k.dtype),
+            jax.ShapeDtypeStruct((B, T, Hkv * D), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(q, k, v, dout, lse, delta)
+    )(qf, kf, vf, dof, lse, delta)
 
-    return dq, dk, dv
+    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))

@@ -56,8 +56,9 @@ def wa_window_update_packed(ring, total, new, idx, full_flag, inv_count):
 
     ring: (I, P) f32; total/new: (P,) f32 with P % ALIGN == 0 (a
     ``packing.PackSpec.padded`` buffer). Exactly one kernel launch; ring
-    and total are donated and updated in place (no per-call pad/reshape
-    copies — the reshapes here are metadata-only bitcasts).
+    and total are donated and updated in place, with no per-call
+    padding. The reshapes to (rows, TILE_COLS) are bitcasts on the CPU;
+    on a TPU, XLA relayouts the (…, P) buffers into the kernel's tiles.
     Returns (ring', total', avg).
     """
     I, Pn = ring.shape

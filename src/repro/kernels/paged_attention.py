@@ -1,5 +1,6 @@
-"""Pallas paged-attention decode kernel (TPU target, validated in
-interpret mode) + the jnp gather reference.
+"""Pallas paged-attention decode kernel (TPU target; numerics checked in
+interpret mode, Mosaic compile checked for v5e) + the jnp gather
+reference.
 
 The serving tier stores K/V in a page pool ``(n_pages, page_size, Hkv,
 D)`` addressed through per-sequence block tables ``(B, table_width)`` —
@@ -38,7 +39,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.flash_attention import NEG_INF, CompilerParams
+from repro.kernels.flash_attention import NEG_INF
 from repro.models.attention import naive_attention
 from repro.models.cache import paged_slot_pages
 
@@ -98,8 +99,8 @@ def _paged_kernel(tbl_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(live)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)            # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)      # (ps, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0].astype(jnp.float32)               # (ps, D)
+        v = v_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * dscale
         if logit_softcap:
@@ -143,7 +144,7 @@ def paged_attention_pallas(q, k_pages, v_pages, tables, lens, *,
     the 128-lane MXU width like ``ops.flash_attention``.
     """
     B, Hq, D = q.shape
-    ps, Hkv = k_pages.shape[1], k_pages.shape[2]
+    NP, ps, Hkv = k_pages.shape[:3]
     G = Hq // Hkv
     TW = tables.shape[1]
     dscale = 1.0 / (D ** 0.5)
@@ -168,10 +169,13 @@ def paged_attention_pallas(q, k_pages, v_pages, tables, lens, *,
         in_specs=[
             pl.BlockSpec((1, 1, G, Dp),
                          lambda b, h, j, tbl, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, ps, 1, Dp),
-                         lambda b, h, j, tbl, ln: (tbl[b, j], 0, h, 0)),
-            pl.BlockSpec((1, ps, 1, Dp),
-                         lambda b, h, j, tbl, ln: (tbl[b, j], 0, h, 0)),
+            # one kv head of one page: a (ps, Dp) lane block of the
+            # (NP, ps, Hkv·Dp) pool view — tile-legal for Mosaic, where a
+            # (…, 1, Dp) block over the head axis is not
+            pl.BlockSpec((1, ps, Dp),
+                         lambda b, h, j, tbl, ln: (tbl[b, j], 0, h)),
+            pl.BlockSpec((1, ps, Dp),
+                         lambda b, h, j, tbl, ln: (tbl[b, j], 0, h)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, Dp),
                                lambda b, h, j, tbl, ln: (b, h, 0, 0)),
@@ -185,10 +189,11 @@ def paged_attention_pallas(q, k_pages, v_pages, tables, lens, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dp), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(tables.astype(jnp.int32), lens.astype(jnp.int32), qg, k_pages, v_pages)
+    )(tables.astype(jnp.int32), lens.astype(jnp.int32), qg,
+      k_pages.reshape(NP, ps, Hkv * Dp), v_pages.reshape(NP, ps, Hkv * Dp))
     return out.reshape(B, Hq, Dp)[..., :D]
 
 

@@ -14,9 +14,9 @@ parameter set (DESIGN.md §2). Two kernels:
        avg       = total' * inv_count         (write; total' written too)
 
    ⇒ 3N reads + 3N writes (total/ring-slot/avg), one pass. The ring slot
-   index and the ``full``/``inv_count`` scalars are scalar-prefetched so
-   the BlockSpec index_map can address ring row ``idx`` directly in HBM —
-   the untouched I−1 rows are never moved.
+   index is scalar-prefetched so the BlockSpec index_map can address ring
+   row ``idx`` directly in HBM — the untouched I−1 rows are never moved;
+   the ``full``/``inv_count`` scalars ride in SMEM.
 
 2. ``online_mean_kernel`` — K-replica mean (W̄ = (1/K)Σ W^k) fused with
    the f32 cast, tiled so each program reads K sub-tiles and writes one.
@@ -57,39 +57,38 @@ TILE_ROWS = 8
 TILE_COLS = 1024
 
 
-# One scalar-prefetch operand carries [idx, full_flag_bits,
-# inv_count_bits] (i32; the f32 scalars are bitcast). Encoder and decoder
-# below are the single source of truth for that positional layout — both
-# window-update kernels decode through them.
+# Scalars: ``idx`` is the one scalar-prefetch operand (an int32 (1,)
+# array) so the ring BlockSpec index_map can address row ``idx``;
+# ``full_flag``/``inv_count`` ride as an f32 (2,) operand in SMEM. (Mosaic
+# has no scalar bitcast, so they cannot be packed into the i32 prefetch.)
+# Encoder and decoder below are the single source of truth for that
+# layout — every window-update kernel decodes through them.
 
 
 def _pack_scalars(idx, full_flag, inv_count):
-    return jnp.stack([
-        idx.astype(jnp.int32),
-        jax.lax.bitcast_convert_type(full_flag.astype(jnp.float32), jnp.int32),
-        jax.lax.bitcast_convert_type(inv_count.astype(jnp.float32), jnp.int32),
-    ])
+    return (jnp.reshape(idx.astype(jnp.int32), (1,)),
+            jnp.stack([full_flag.astype(jnp.float32),
+                       inv_count.astype(jnp.float32)]))
 
 
-def _unpack_scalars(scalars_ref):
-    """(full_flag, inv_count) as f32; the idx slot is only read by the
-    ring BlockSpec index_map (scalar prefetch)."""
-    return (jax.lax.bitcast_convert_type(scalars_ref[1], jnp.float32),
-            jax.lax.bitcast_convert_type(scalars_ref[2], jnp.float32))
+def _unpack_scalars(fscal_ref):
+    """(full_flag, inv_count) as f32 scalars read from SMEM."""
+    return fscal_ref[0], fscal_ref[1]
 
 
 # Shared BlockSpecs: the ring is addressed at HBM row ``idx`` straight
-# from the prefetched scalars (the untouched I−1 rows are never moved);
-# flat operands tile the (R, C) plane.
+# from the prefetched scalar (the untouched I−1 rows are never moved);
+# flat operands tile the (R, C) plane; the f32 scalars sit whole in SMEM.
 _RING_SPEC = pl.BlockSpec((1, TILE_ROWS, TILE_COLS),
                           lambda i, j, s: (s[0], i, j))
 _FLAT_SPEC = pl.BlockSpec((TILE_ROWS, TILE_COLS), lambda i, j, s: (i, j))
+_SMEM_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
-def _wa_window_update_kernel(scalars_ref, ring_ref, total_ref, new_ref,
-                             ring_out_ref, total_out_ref, avg_ref):
+def _wa_window_update_kernel(idx_ref, fscal_ref, ring_ref, total_ref,
+                             new_ref, ring_out_ref, total_out_ref, avg_ref):
     """One (TILE_ROWS, TILE_COLS) tile of the fused window update."""
-    full, inv_count = _unpack_scalars(scalars_ref)
+    full, inv_count = _unpack_scalars(fscal_ref)
     old = ring_ref[0]                       # ring block is (1, rows, cols)
     new = new_ref[...]
     total = total_ref[...] + new - full * old
@@ -110,7 +109,7 @@ def wa_window_update_2d(ring, total, new, idx, full_flag, inv_count,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(R // TILE_ROWS, C // TILE_COLS),
-        in_specs=[_RING_SPEC, _FLAT_SPEC, _FLAT_SPEC],
+        in_specs=[_SMEM_SPEC, _RING_SPEC, _FLAT_SPEC, _FLAT_SPEC],
         out_specs=[_RING_SPEC, _FLAT_SPEC, _FLAT_SPEC],
     )
     ring_out, total_out, avg = pl.pallas_call(
@@ -119,17 +118,17 @@ def wa_window_update_2d(ring, total, new, idx, full_flag, inv_count,
         out_shape=[jax.ShapeDtypeStruct(ring.shape, jnp.float32),
                    jax.ShapeDtypeStruct(total.shape, jnp.float32),
                    jax.ShapeDtypeStruct(total.shape, jnp.float32)],
-        input_output_aliases={1: 0, 2: 1},   # ring->ring_out, total->total_out
+        input_output_aliases={2: 0, 3: 1},   # ring->ring_out, total->total_out
         interpret=interpret,
-    )(_pack_scalars(idx, full_flag, inv_count), ring, total, new)
+    )(*_pack_scalars(idx, full_flag, inv_count), ring, total, new)
     return ring_out, total_out, avg
 
 
-def _wa_sync_fused_kernel(scalars_ref, stacked_ref, ring_ref, total_ref,
-                          ring_out_ref, total_out_ref, avg_ref, *,
-                          inv_k: float):
+def _wa_sync_fused_kernel(idx_ref, fscal_ref, stacked_ref, ring_ref,
+                          total_ref, ring_out_ref, total_out_ref, avg_ref,
+                          *, inv_k: float):
     """One tile of the fused K-replica-mean + window update (whole sync)."""
-    full, inv_count = _unpack_scalars(scalars_ref)
+    full, inv_count = _unpack_scalars(fscal_ref)
     mean = jnp.sum(stacked_ref[...].astype(jnp.float32), axis=0) * inv_k
     old = ring_ref[0]                       # ring block is (1, rows, cols)
     total = total_ref[...] + mean - full * old
@@ -155,7 +154,7 @@ def wa_sync_fused_2d(stacked, ring, total, idx, full_flag, inv_count,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(R // TILE_ROWS, C // TILE_COLS),
-        in_specs=[stacked_spec, _RING_SPEC, _FLAT_SPEC],
+        in_specs=[_SMEM_SPEC, stacked_spec, _RING_SPEC, _FLAT_SPEC],
         out_specs=[_RING_SPEC, _FLAT_SPEC, _FLAT_SPEC],
     )
     ring_out, total_out, avg = pl.pallas_call(
@@ -164,19 +163,19 @@ def wa_sync_fused_2d(stacked, ring, total, idx, full_flag, inv_count,
         out_shape=[jax.ShapeDtypeStruct(ring.shape, jnp.float32),
                    jax.ShapeDtypeStruct(total.shape, jnp.float32),
                    jax.ShapeDtypeStruct(total.shape, jnp.float32)],
-        input_output_aliases={2: 0, 3: 1},   # ring->ring_out, total->total_out
+        input_output_aliases={3: 0, 4: 1},   # ring->ring_out, total->total_out
         interpret=interpret,
-    )(_pack_scalars(idx, full_flag, inv_count), stacked, ring, total)
+    )(*_pack_scalars(idx, full_flag, inv_count), stacked, ring, total)
     return ring_out, total_out, avg
 
 
-def _wa_window_update_c_kernel(scalars_ref, ring_ref, total_ref, comp_ref,
-                               new_ref, ring_out_ref, total_out_ref,
-                               comp_out_ref, avg_ref):
+def _wa_window_update_c_kernel(idx_ref, fscal_ref, ring_ref, total_ref,
+                               comp_ref, new_ref, ring_out_ref,
+                               total_out_ref, comp_out_ref, avg_ref):
     """Compressed-ring tile: ring stored in a narrow dtype (bf16), total
     f32 with Kahan compensation. The down/up-casts ride the same single
     pass — every byte is already in VMEM."""
-    full, inv_count = _unpack_scalars(scalars_ref)
+    full, inv_count = _unpack_scalars(fscal_ref)
     old = ring_ref[0].astype(jnp.float32)
     slot = new_ref[...].astype(ring_out_ref.dtype)
     stored = slot.astype(jnp.float32)
@@ -202,7 +201,8 @@ def wa_window_update_c_2d(ring, total, comp, new, idx, full_flag, inv_count,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(R // TILE_ROWS, C // TILE_COLS),
-        in_specs=[_RING_SPEC, _FLAT_SPEC, _FLAT_SPEC, _FLAT_SPEC],
+        in_specs=[_SMEM_SPEC, _RING_SPEC, _FLAT_SPEC, _FLAT_SPEC,
+                  _FLAT_SPEC],
         out_specs=[_RING_SPEC, _FLAT_SPEC, _FLAT_SPEC, _FLAT_SPEC],
     )
     ring_out, total_out, comp_out, avg = pl.pallas_call(
@@ -213,18 +213,19 @@ def wa_window_update_c_2d(ring, total, comp, new, idx, full_flag, inv_count,
                    jax.ShapeDtypeStruct(comp.shape, jnp.float32),
                    jax.ShapeDtypeStruct(total.shape, jnp.float32)],
         # ring->ring_out, total->total_out, comp->comp_out
-        input_output_aliases={1: 0, 2: 1, 3: 2},
+        input_output_aliases={2: 0, 3: 1, 4: 2},
         interpret=interpret,
-    )(_pack_scalars(idx, full_flag, inv_count), ring, total, comp, new)
+    )(*_pack_scalars(idx, full_flag, inv_count), ring, total, comp, new)
     return ring_out, total_out, comp_out, avg
 
 
-def _wa_sync_fused_c_kernel(scalars_ref, stacked_ref, ring_ref, total_ref,
-                            comp_ref, ring_out_ref, total_out_ref,
-                            comp_out_ref, avg_ref, *, inv_k: float):
+def _wa_sync_fused_c_kernel(idx_ref, fscal_ref, stacked_ref, ring_ref,
+                            total_ref, comp_ref, ring_out_ref,
+                            total_out_ref, comp_out_ref, avg_ref, *,
+                            inv_k: float):
     """Fused sync tile over a compressed ring: K-mean, narrow-dtype slot
     write, Kahan-compensated f32 total — one pass."""
-    full, inv_count = _unpack_scalars(scalars_ref)
+    full, inv_count = _unpack_scalars(fscal_ref)
     mean = jnp.sum(stacked_ref[...].astype(jnp.float32), axis=0) * inv_k
     old = ring_ref[0].astype(jnp.float32)
     slot = mean.astype(ring_out_ref.dtype)
@@ -252,7 +253,8 @@ def wa_sync_fused_c_2d(stacked, ring, total, comp, idx, full_flag,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(R // TILE_ROWS, C // TILE_COLS),
-        in_specs=[stacked_spec, _RING_SPEC, _FLAT_SPEC, _FLAT_SPEC],
+        in_specs=[_SMEM_SPEC, stacked_spec, _RING_SPEC, _FLAT_SPEC,
+                  _FLAT_SPEC],
         out_specs=[_RING_SPEC, _FLAT_SPEC, _FLAT_SPEC, _FLAT_SPEC],
     )
     ring_out, total_out, comp_out, avg = pl.pallas_call(
@@ -263,9 +265,9 @@ def wa_sync_fused_c_2d(stacked, ring, total, comp, idx, full_flag,
                    jax.ShapeDtypeStruct(comp.shape, jnp.float32),
                    jax.ShapeDtypeStruct(total.shape, jnp.float32)],
         # ring->ring_out, total->total_out, comp->comp_out
-        input_output_aliases={2: 0, 3: 1, 4: 2},
+        input_output_aliases={3: 0, 4: 1, 5: 2},
         interpret=interpret,
-    )(_pack_scalars(idx, full_flag, inv_count), stacked, ring, total, comp)
+    )(*_pack_scalars(idx, full_flag, inv_count), stacked, ring, total, comp)
     return ring_out, total_out, comp_out, avg
 
 

@@ -1,8 +1,8 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 # The dry-run only lowers and INTROSPECTS compiled artifacts — no tensor
-# is ever materialized, so the XLA-0.4.37 CPU miscompile of the legacy
-# GSPMD packed-W̄ assembly (launch/sync/legacy.py) cannot corrupt
+# is ever materialized, so the CPU miscompile of the legacy GSPMD
+# packed-W̄ assembly (launch/sync/legacy.py) cannot corrupt
 # anything here. Allow the FSDP hwa_sync combos to keep compiling on the
 # forced-host meshes instead of tripping the hard error.
 os.environ.setdefault("REPRO_ALLOW_LEGACY_ASSEMBLY", "1")
@@ -195,8 +195,6 @@ def run_combo(arch, shape_name, mesh_kind, step_kind="auto", hwa_k=2,
     t2 = time.time()
 
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):    # jax 0.4.x returns [dict]
-        ca = ca[0] if ca else {}
     ma = compiled.memory_analysis()
     # loop-aware structural analysis (XLA cost_analysis counts while
     # bodies once — verified; analyze_hlo multiplies trip counts)

@@ -6,8 +6,8 @@ multi-pod = 2 pods = 512 chips, axes (pod, data, model). For HWA the
 replica axis is the pod axis at multi-pod scale, or carved out of the data
 axis on a single pod (DESIGN.md §2).
 
-Mesh construction goes through ``repro.common.compat.make_mesh`` so the
-same code runs on jax 0.4.x and newer releases.
+Mesh construction goes through ``repro.common.compat.make_mesh`` (every
+axis ``Auto``).
 """
 from __future__ import annotations
 

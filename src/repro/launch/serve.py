@@ -1,7 +1,11 @@
-"""Serving launcher: batched decode against a smoke-scale model.
+"""Serving launcher: batched decode against a randomly initialized model
+(``--preset smoke``, the CPU-scale variant, by default; ``--preset full
+--n-layers N`` serves the published widths with only the depth cut).
 
   PYTHONPATH=src python -m repro.launch.serve --arch musicgen-medium \
       --batch 4 --prompt-len 16 --new-tokens 16
+  python -m repro.launch.serve --preset full --n-layers 1 --engine paged \
+      --batch 4 --prompt-len 128 --new-tokens 16 --page-size 16
 
 ``--engine paged`` routes through the production tier (paged KV cache +
 continuous-batching scheduler + single fixed-shape jitted step); the
@@ -15,7 +19,9 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro.configs import ARCH_IDS, get_smoke_config
+from repro.common.compile_cache import use_compile_cache
+from repro.configs import ARCH_IDS
+from repro.launch.train import add_preset_args, launcher_config
 from repro.models.registry import build_model
 from repro.serve.engine import DecodeEngine, PagedDecodeEngine
 
@@ -23,6 +29,12 @@ from repro.serve.engine import DecodeEngine, PagedDecodeEngine
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-2b", choices=ARCH_IDS)
+    add_preset_args(ap)
+    ap.add_argument("--attn-impl", default="",
+                    choices=["", "naive", "flash_jnp", "flash_pallas"],
+                    help="override the arch's attention implementation "
+                         "(--preset full defaults to flash_pallas, which "
+                         "also selects the Pallas paged-decode kernel)")
     ap.add_argument("--engine", default="naive", choices=["naive", "paged"])
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)
@@ -30,8 +42,9 @@ def main():
     ap.add_argument("--page-size", type=int, default=4)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args()
+    use_compile_cache()
 
-    cfg = get_smoke_config(args.arch)
+    cfg = launcher_config(args)
     lm = build_model(cfg)
     params = lm.init(jax.random.key(0))
     key = jax.random.key(1)

@@ -1,12 +1,15 @@
 """Training launcher: HWA (and baselines) on any assigned architecture.
 
-CPU-scale entry point (smoke configs by default) that exercises the full
-stack: config registry → synthetic data → HWA trainer → checkpoints.
-The production path for real hardware is the same Trainer with the
-HWA mesh (``repro.launch.mesh.make_hwa_mesh``) — see examples/.
+Entry point for the full stack: config registry → synthetic data → HWA
+trainer → checkpoints. ``--preset smoke`` (default) is the CPU-scale
+variant; ``--preset full --n-layers N`` is the published config at its
+published widths with only the depth cut, which is what runs on a TPU
+(flash attention and the fused WA sync as compiled Pallas kernels):
 
   PYTHONPATH=src python -m repro.launch.train --arch granite-3-2b \
       --method hwa --steps 300 --k 2 --window 10
+  python -m repro.launch.train --preset full --n-layers 1 --steps 8 \
+      --sync-period 4 --window 2 --batch-size 4 --seq-len 1024
 
 ``--mesh-native`` instead runs the shard_map SPMD path: K replicas on the
 ``replica`` mesh axis, one weight pmean per sync (no devices? force host
@@ -29,11 +32,42 @@ import argparse
 import json
 import os
 
-from repro.configs import ARCH_IDS, get_smoke_config
+from repro.common.compile_cache import use_compile_cache
+from repro.configs import ARCH_IDS, PRESETS, get_preset
 from repro.core.hwa import HWAConfig
 from repro.data import DataPipeline, make_markov_lm_dataset
 from repro.models.registry import build_model
 from repro.train.trainer import TrainConfig, Trainer, lm_task
+
+
+def add_preset_args(ap: argparse.ArgumentParser) -> None:
+    """``--preset``/``--n-layers``, shared with ``repro.launch.serve``."""
+    ap.add_argument("--preset", default="smoke", choices=PRESETS,
+                    help="smoke: CPU-scale variant; full: the published "
+                         "config at its published widths")
+    ap.add_argument("--n-layers", type=int, default=0,
+                    help="--preset full only: cut the depth to N layers "
+                         "(0 = the published depth)")
+
+
+def launcher_config(args):
+    """The model config a launcher runs: ``--preset``/``--n-layers``,
+    then ``--attn-impl`` (``--preset full`` defaults to flash_pallas).
+    Prints the config and the keys cut from the published one."""
+    if args.n_layers and args.preset != "full":
+        raise SystemExit("--n-layers cuts the published config; add "
+                         "--preset full")
+    cfg, reduced = get_preset(args.arch, args.preset, args.n_layers)
+    attn = args.attn_impl or ("flash_pallas" if args.preset == "full"
+                              else "")
+    if attn:
+        cfg = cfg.with_(attn_impl=attn)
+    cut = "smoke widths" if reduced is None else f"reduced={reduced}"
+    print(f"[config] {cfg.name} ({args.preset}): layers={cfg.n_layers} "
+          f"d_model={cfg.d_model} heads={cfg.n_heads}/{cfg.n_kv_heads} "
+          f"d_ff={cfg.d_ff} vocab={cfg.vocab_size} attn={cfg.attn_impl} "
+          f"dtype={cfg.dtype} {cut}")
+    return cfg
 
 
 def run_mesh_native(args) -> dict:
@@ -55,7 +89,7 @@ def run_mesh_native(args) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.common.compat import make_mesh, use_mesh
+    from repro.common.compat import make_mesh
     from repro.common.quant import is_compressed, needs_scales
     from repro.launch.specs import input_specs
     from repro.launch.steps import (SyncPlan, TwoLevel, build_hwa_bundles,
@@ -87,9 +121,7 @@ def run_mesh_native(args) -> dict:
         replica_axis = "replica"
         topo = None
     rules = make_tp_rules(mesh, replica_axis=replica_axis, fsdp=args.fsdp)
-    cfg = get_smoke_config(args.arch)
-    if args.attn_impl:
-        cfg = cfg.with_(attn_impl=args.attn_impl)
+    cfg = launcher_config(args)
     if cfg.attn_impl == "flash_pallas" and tp > 1:
         raise SystemExit("--attn-impl flash_pallas runs the fully-manual "
                          "DP-only train step; --tp must stay 1")
@@ -201,7 +233,7 @@ def run_mesh_native(args) -> dict:
             history = list(meta.get("history", []))
             print(f"[mesh-native] resumed from step {start_step} "
                   f"({session.step_dir(latest)})")
-    with use_mesh(mesh):
+    with mesh:
         for step in range(start_step, args.steps):
             if inject is not None and step == inject[0]:
                 from repro.resilience.faults import poison_replica
@@ -297,6 +329,7 @@ def run_mesh_native(args) -> dict:
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-2b", choices=ARCH_IDS)
+    add_preset_args(ap)
     ap.add_argument("--method", default="hwa",
                     choices=["base", "ca", "swa", "ema", "lookahead", "sam",
                              "online", "pmsgd", "hwa"])
@@ -310,7 +343,8 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--attn-impl", default="",
                     choices=["", "naive", "flash_jnp", "flash_pallas"],
-                    help="override the arch's attention implementation; "
+                    help="override the arch's attention implementation "
+                         "(--preset full defaults to flash_pallas); "
                          "flash_pallas selects the Pallas custom-vjp "
                          "kernels (fully-manual DP train step under "
                          "--mesh-native; interpret mode off-TPU)")
@@ -377,6 +411,7 @@ def main():
                          "--checkpoint-dir (bit-exact: torn/corrupted "
                          "saves are skipped)")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.inject_nan and not args.mesh_native:
         raise SystemExit("--inject-nan needs --mesh-native (use "
@@ -399,9 +434,7 @@ def main():
                            if not k.startswith("_")}, f, indent=2)
         return
 
-    cfg = get_smoke_config(args.arch)
-    if args.attn_impl:
-        cfg = cfg.with_(attn_impl=args.attn_impl)
+    cfg = launcher_config(args)
     if cfg.family in ("vlm", "audio"):
         raise SystemExit(f"{args.arch}: use examples/serve_decode.py-style "
                          "drivers for modality-frontend archs")
@@ -416,7 +449,8 @@ def main():
         batch_size=args.batch_size, base_lr=args.lr, seed=args.seed,
         hwa=HWAConfig(n_replicas=K, sync_period=args.sync_period,
                       window=args.window, resilient=args.resilient,
-                      max_param_rms=args.max_param_rms or None),
+                      max_param_rms=args.max_param_rms or None,
+                      use_kernels=True),
         checkpoint_dir=args.checkpoint_dir,
         checkpoint_every=args.checkpoint_every,
         checkpoint_keep=args.keep, resume=args.resume)

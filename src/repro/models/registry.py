@@ -162,8 +162,16 @@ def _xent(logits, targets):
     """Mean token cross-entropy in f32. logits (..., V), targets (...)."""
     logits = logits.astype(jnp.float32)
     logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    return jnp.mean(logz - gold)
+    return jnp.mean(logz - _gold_logit(logits, targets))
+
+
+def _gold_logit(logits, targets):
+    """logits[..., targets] as a one-hot contraction (exact: one nonzero
+    term). A take_along_axis gather over a vocab-sharded logits tensor
+    with a data-sharded batch aborts XLA's SPMD partitioner inside the
+    mesh-native partial-manual train step."""
+    return jnp.sum(logits * jax.nn.one_hot(targets, logits.shape[-1],
+                                           dtype=logits.dtype), axis=-1)
 
 
 _XENT_CHUNK = 512
@@ -203,8 +211,7 @@ def _head_and_xent(cfg, params, x, targets):
         xb, tb = xt
         logits = head_logits(xb)
         logz = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, tb[..., None], axis=-1)[..., 0]
-        loss_sum = jnp.sum(logz - gold)
+        loss_sum = jnp.sum(logz - _gold_logit(logits, tb))
         acc_sum = jnp.sum((jnp.argmax(logits, -1) == tb).astype(jnp.float32))
         return (carry[0] + loss_sum, carry[1] + acc_sum), None
 

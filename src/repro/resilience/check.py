@@ -70,7 +70,8 @@ def _mesh_args(**kw):
     """An argparse.Namespace for ``launch.train.run_mesh_native`` with
     the launcher's defaults (tiny smoke config)."""
     ns = argparse.Namespace(
-        arch="granite-3-2b", k=2, tp=1, fsdp=False, sync_tree="flat",
+        arch="granite-3-2b", preset="smoke", n_layers=0, k=2, tp=1,
+        fsdp=False, sync_tree="flat",
         pods=0, outer_every=2, window=3, seq_len=16, batch_size=4,
         lr=0.3, seed=0, steps=8, sync_period=2, attn_impl="",
         resilient=False, max_param_rms=0.0, inject_nan="",

@@ -163,6 +163,7 @@ class PagedDecodeEngine:
         self.prefix_len = _prefix_len(cfg)
         self._step_traces = 0
         self._prefill_traces = 0
+        self.last_logits = None       # (B, V) f32 of the latest step
         self._jit_step = self._build_step()
         self._jit_prefill = self._build_prefill()
         self._jit_prefix = self._build_prefix_fill()
@@ -220,7 +221,7 @@ class PagedDecodeEngine:
             sampled = _sample(logits, sub, temp).astype(jnp.int32)
             out = out.at[jnp.arange(out.shape[0]),
                          ctrl["out_idx"]].set(sampled)
-            return caches, sampled, out, key
+            return caches, sampled, out, key, logits
 
         return jax.jit(step, donate_argnums=(1, 2, 3))
 
@@ -259,10 +260,11 @@ class PagedDecodeEngine:
     def step(self, ctrl: dict):
         """One fixed-shape decode step. ``ctrl`` holds host-built arrays:
         tables (B,TW) i32, pos (B,) i32, use_prompt (B,) bool,
-        prompt_tok (B,)/(B,CB) i32, out_idx (B,) i32, reset (B,) bool."""
+        prompt_tok (B,)/(B,CB) i32, out_idx (B,) i32, reset (B,) bool.
+        The step's f32 logits stay on device as ``last_logits``."""
         s = self.state
         dev_ctrl = {k: jnp.asarray(v) for k, v in ctrl.items()}
-        caches, last, out, key = self._jit_step(
+        caches, last, out, key, self.last_logits = self._jit_step(
             self.params, s["caches"], s["last"], s["out"], s["key"], dev_ctrl)
         self.state = {"caches": caches, "last": last, "out": out, "key": key}
 

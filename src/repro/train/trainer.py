@@ -19,6 +19,7 @@ best snapshot (paper §IV-C early-stopping remark).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Any, Callable
 
@@ -125,13 +126,14 @@ class Trainer:
         task, tc, opt = self.task, self.tc, self.optimizer
         loss_fn, sched = task.loss_fn, self.schedule
 
-        @jax.jit
+        # both steps donate the state: only the returned one stays live
+        @functools.partial(jax.jit, donate_argnums=(0,))
         def hwa_step(state: HWAState, step):
             batches = task.pipeline.stacked_batch(step)
             return hwa_inner_step(self.hwa_cfg, state, batches, loss_fn,
                                   opt, sched(step))
 
-        @jax.jit
+        @functools.partial(jax.jit, donate_argnums=(0,))
         def sync_step(state: HWAState):
             return hwa_sync(self.hwa_cfg, state)
 
@@ -154,7 +156,8 @@ class Trainer:
                                              "targets": targets})
             return metrics["loss"], metrics.get("acc", jnp.zeros(()))
 
-        self._hwa_step, self._sync_step = hwa_step, sync_step
+        # public: a caller may lower them to inspect the compiled step
+        self.hwa_step, self.sync_step = hwa_step, sync_step
         self._single_step, self._eval_batch = single_step, eval_batch
         self._swa_update = jax.jit(swa_update)
         self._ema_update = jax.jit(ema_update)
@@ -173,7 +176,14 @@ class Trainer:
 
     # ------------------------------------------------------------- run
 
-    def run(self, eval_views: bool = False, log: bool = False) -> dict:
+    def run(self, eval_views: bool = False, log: bool = False,
+            on_step: Callable | None = None) -> dict:
+        """Train for ``total_steps``. For the K-replica methods,
+        ``on_step(step, state, metrics)`` is called after every inner
+        step with the live :class:`HWAState`, before that step's sync
+        (if any) consumes it. It runs synchronously, so a caller can time
+        steps or copy a pre-sync state out to check the sync.
+        """
         tc = self.tc
         key = jax.random.key(tc.seed)
         params = self.task.init(key)
@@ -229,8 +239,10 @@ class Trainer:
                               f"from step {start_step} "
                               f"({session.step_dir(start_step)})")
             for step in range(start_step, tc.total_steps):
-                state, metrics = self._hwa_step(state, step)
+                state, metrics = self.hwa_step(state, step)
                 train_loss = metrics["loss"]
+                if on_step is not None:
+                    on_step(step, state, metrics)
                 if (step + 1) % self.sync_period == 0:
                     views = None
                     if eval_views:
@@ -242,7 +254,7 @@ class Trainer:
                                 lambda x: jnp.mean(x, 0).astype(x.dtype),
                                 state.inner),
                         }
-                    state, _ = self._sync_step(state)
+                    state, _ = self.sync_step(state)
                     if ((step + 1) // self.sync_period) % max(
                             eval_every // self.sync_period, 1) == 0:
                         record(step + 1, train_loss, state.wa, views)

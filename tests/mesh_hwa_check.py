@@ -38,13 +38,14 @@ Verifies the tentpole properties of mesh-native HWA on a (2,2,2)
      fp8 tree's cross-pod hop compiles to the u8-payload + f32-scales
      all-gather pair (the integer bit-view XLA cannot widen).
 
-All oracles are computed on HOST-materialized copies: eagerly packing
-DISTRIBUTED leaves (a concat across differently-sharded operands) is
-miscompiled by XLA 0.4.37's CPU SPMD partitioner — replicated shards get
+All oracles are computed on HOST-materialized copies, so no oracle
+shares a partitioner pattern with the code it checks: eagerly packing
+DISTRIBUTED leaves (a concat across differently-sharded operands) was
+once miscompiled by XLA's CPU SPMD partitioner, replicated shards
 overcounted ~(data×model)-fold. The legacy GSPMD sync path hit the same
-partitioner pattern in-jit, which is why the mesh-resident layout now
-assembles shard-locally and leaves nothing for the partitioner to get
-wrong (the legacy fallback is still asserted, structurally only, below).
+pattern in-jit; the mesh-resident layout assembles shard-locally and
+leaves nothing for the partitioner to get wrong (the legacy fallback is
+asserted, structurally only, below).
 """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -53,7 +54,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.common.compat import use_mesh
 from repro.common.packing import pack_spec, pack_stacked, unpack
 from repro.configs import get_smoke_config
 from repro.core.hwa import HWAConfig
@@ -131,7 +131,7 @@ def mk_sync(lm_, rules_, hwa, **kw):
 mesh_train = mk_train(lm, rules, hwa_cfg)
 mesh_train_c = mesh_train.lower(mesh).compile()
 a_inner, a_opt = stack2(params), jax.vmap(opt.init)(stack2(params))
-with use_mesh(mesh):
+with mesh:
     for step in range(N_STEPS):
         a_inner, a_opt, a_losses = mesh_train_c(a_inner, a_opt,
                                                 batches(step))
@@ -142,7 +142,7 @@ check("mesh-native: finite per-replica losses",
 vmap_train = mk_train(lm, rules, hwa_cfg, mesh_native=False)
 vmap_train_c = vmap_train.lower(mesh).compile()
 b_inner, b_opt = stack2(params), jax.vmap(opt.init)(stack2(params))
-with use_mesh(mesh):
+with mesh:
     for step in range(N_STEPS):
         b_inner, b_opt, _ = vmap_train_c(b_inner, b_opt, batches(step))
 
@@ -182,7 +182,7 @@ check(f"sync: pack_spec is shard-aware (axes={spec.axes}, "
 ring = jnp.zeros((hwa_cfg.window, spec.padded), jnp.float32)
 total = jnp.zeros((spec.padded,), jnp.float32)
 zero = jnp.zeros((), jnp.int32)
-with use_mesh(mesh):
+with mesh:
     (s_inner, s_ring, s_total, s_count, s_nidx, s_wa,
      s_cycle) = sync_c(a_inner, ring, total, zero, zero, zero)
 check("sync: replicas equal after restart",
@@ -206,7 +206,7 @@ sync_kc = sync_k.lower(mesh).compile()
 spec_k = sync_k.pack_spec
 ring_k = jnp.zeros((hwa_cfg_k.window, spec_k.padded), jnp.float32)
 total_k = jnp.zeros((spec_k.padded,), jnp.float32)
-with use_mesh(mesh):
+with mesh:
     out_k = sync_kc(a_inner2, ring_k, total_k, zero, zero, zero)
 (k_inner, k_ring, k_total, k_count, k_nidx, k_wa, k_cycle) = out_k
 k_ring_h, k_total_h = to_host(k_ring), to_host(k_total)
@@ -266,11 +266,10 @@ for label, compiled in [("sync", sync_c), ("kernel sync", sync_kc)]:
           f"(non-replica crossings: {n_other})", audit["assembly_free"])
 
 # the legacy (non-mesh-resident) fallback is a HARD ERROR on multi-device
-# CPU meshes (XLA 0.4.37 miscompiles its packed-W̄ assembly — see
-# launch/sync/legacy.py); REPRO_ALLOW_LEGACY_ASSEMBLY=1 is the escape
-# hatch for HLO-introspection-only callers, under which it still compiles
-# and structurally pays the assembly redistribution the aligned layout
-# removes
+# CPU meshes (see launch/sync/legacy.py); REPRO_ALLOW_LEGACY_ASSEMBLY=1
+# is the escape hatch for HLO-introspection-only callers, under which it
+# still compiles and structurally pays the assembly redistribution the
+# aligned layout removes
 _prior_hatch = os.environ.pop("REPRO_ALLOW_LEGACY_ASSEMBLY", None)
 try:
     legacy_raised = False
@@ -313,7 +312,7 @@ check("fsdp sync: grouped layout chosen, no legacy-assembly error "     # raise
 spec_f = sync_f.pack_spec
 sync_fc = sync_f.lower(mesh).compile()
 ring_f, total_f = window_buffers(spec_f, hwa_cfg_k.window)
-with use_mesh(mesh):
+with mesh:
     (fs_inner, fs_ring, fs_total, fs_count, fs_nidx, fs_wa,
      fs_cycle) = sync_fc(jax.tree.map(jnp.array, a_host2), ring_f, total_f,
                          zero, zero, zero)
@@ -368,7 +367,7 @@ def fresh_window_r():
 
 
 ring_r, total_r = fresh_window_r()
-with use_mesh(mesh):
+with mesh:
     (r_inner, r_ring, r_total, r_count, r_nidx, r_wa, r_cycle,
      r_alive) = sync_rc(jax.tree.map(jnp.array, a_host), ring_r, total_r,
                         zero, zero, zero)
@@ -387,7 +386,7 @@ check("resilient sync (all healthy): counters match plain sync",
 # every replica restarts bit-equal to replica 0's pre-sync weights
 poisoned = jax.tree.map(jnp.array, poison_replica(a_host, 1))
 ring_r, total_r = fresh_window_r()
-with use_mesh(mesh):
+with mesh:
     (p_inner, _, _, _, _, p_wa, _, p_alive) = sync_rc(
         poisoned, ring_r, total_r, zero, zero, zero)
 check("resilient sync (poisoned): alive mask excludes replica 1",
@@ -461,7 +460,7 @@ def batches4(step):
 
 stack4 = lambda t: jax.tree.map(lambda x: jnp.stack([x] * K4), t)
 t_inner0, t_opt0 = stack4(params), jax.vmap(opt.init)(stack4(params))
-with use_mesh(mesh_t):
+with mesh_t:
     t_inner0, t_opt0, t_losses = tree_train_c(t_inner0, t_opt0, batches4(0))
 check("tree train step: finite per-replica losses",
       bool(jnp.all(jnp.isfinite(t_losses))))
@@ -485,7 +484,7 @@ def run_sync(bundle, run_mesh, state, with_cycle):
     total_ = jnp.zeros((spec_.padded,), jnp.float32)
     c = bundle.lower(run_mesh).compile()
     extra = (zero,) if with_cycle else ()
-    with use_mesh(run_mesh):
+    with run_mesh:
         return c(state, ring_, total_, zero, zero, *extra), c
 
 
@@ -535,7 +534,7 @@ check("two-level outer sync: audit outer_sync_ok "
 # ... and the INNER sync crosses ONLY the inner (per-pod) groups
 inner_b = tree_bundles.inner_sync
 inner_c = inner_b.lower(mesh_t).compile()
-with use_mesh(mesh_t):
+with mesh_t:
     i_inner = inner_c(jax.tree.map(jnp.array, div4_host))
 audit_inner = sync_collective_audit(inner_c.as_text(), mesh_t,
                                     replica_axis="replica",
@@ -566,7 +565,7 @@ hwa8 = HWAConfig(n_replicas=K8, window=3, use_kernels=True)
 flat8 = mk_sync(lm, rules, hwa8, mesh_native=False)  # k_local=4
 spec8 = flat8.pack_spec
 flat8_c = flat8.lower(mesh).compile()
-with use_mesh(mesh):
+with mesh:
     out8 = flat8_c(jax.tree.map(jnp.array, div8_host),
                    jnp.zeros((hwa8.window, spec8.padded), jnp.float32),
                    jnp.zeros((spec8.padded,), jnp.float32), zero, zero)
@@ -590,7 +589,7 @@ lm_fp = build_model(cfg_fp)
 flash_train = mk_train(lm_fp, rules, hwa_cfg)
 flash_train_c = flash_train.lower(mesh).compile()
 fp_inner, fp_opt = stack2(params), jax.vmap(opt.init)(stack2(params))
-with use_mesh(mesh):
+with mesh:
     for step in range(N_STEPS):
         fp_inner, fp_opt, fp_losses = flash_train_c(fp_inner, fp_opt,
                                                     batches(step))
@@ -685,7 +684,7 @@ from repro.common.quant import decode_slot, encode_slot
 sync_bf = mk_sync(lm, rules, hwa_cfg_k, wa_dtype="bf16")
 win_bf = window_state_args(sync_bf)
 nb = len(win_bf) - 3                      # ring, [scales], ..., [comp]
-with use_mesh(mesh):
+with mesh:
     out_bf = sync_bf.lower(mesh).compile()(
         jax.tree.map(jnp.array, a_host), *win_bf)
 bf_inner, bf_wa = out_bf[0], out_bf[3 + nb]
@@ -705,7 +704,7 @@ sync_f8 = build_hwa_bundles(
 win_f8 = window_state_args(sync_f8)
 nf = len(win_f8) - 3
 f8_c = sync_f8.lower(mesh_t).compile()
-with use_mesh(mesh_t):
+with mesh_t:
     out_f8 = f8_c(jax.tree.map(jnp.array, div4_host), *win_f8)
 err_f8 = max_rel_ulp(t_wa, out_f8[3 + nf], "fp8")
 check(f"fp8 tree sync (fp8 ring + fp8 comms): W̿ within "

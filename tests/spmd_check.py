@@ -13,7 +13,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.common.compat import use_mesh
 from repro.configs import get_smoke_config
 from repro.core.hwa import HWAConfig
 from repro.launch.mesh import make_test_mesh
@@ -38,7 +37,7 @@ mesh = make_test_mesh((2, 4), ("data", "model"))
 rules = make_tp_rules(mesh)
 emb = jax.random.normal(jax.random.key(0), (32, 16))
 ids = jax.random.randint(jax.random.key(1), (4, 6), 0, 32)
-with use_mesh(mesh):
+with mesh:
     got = jax.jit(lambda e, i: _sharded_gather(e, i, rules))(emb, ids)
 want = jnp.take(emb, ids, axis=0)
 check("sharded_gather == take",
@@ -51,7 +50,7 @@ cfg = get_smoke_config("granite-moe-1b-a400m")  # 4 experts % 4 == 0
 p, _ = init_moe(cfg, jax.random.key(0), jnp.float32)
 x = jax.random.normal(jax.random.key(1), (4, 8, cfg.d_model))
 want, aux_w = moe_forward(cfg, p, x)
-with use_mesh(mesh):
+with mesh:
     got, aux_g = jax.jit(lambda p, x: moe_forward_ep(
         cfg, p, x, mesh=mesh, capacity_factor=4.0))(p, x)
 check("EP MoE == TP MoE",
@@ -85,7 +84,7 @@ batch = {
     "targets": jax.random.randint(jax.random.key(3), (K, 8, 16), 0,
                                   cfg_lm.vocab_size),
 }
-with use_mesh(mesh3):
+with mesh3:
     new_stacked, new_opt, loss = compiled(stacked, opt_state, batch)
 check("hwa_train_step runs; finite loss", bool(jnp.isfinite(loss)))
 
@@ -116,7 +115,7 @@ spec = sync.pack_spec               # window state is packed (I, P)/(P,)
 ring = jnp.zeros((I, spec.padded), jnp.float32)
 total = jnp.zeros((spec.padded,), jnp.float32)
 zero = jnp.zeros((), jnp.int32)
-with use_mesh(mesh3):
+with mesh3:
     out = sync_c(new_stacked, ring, total, zero, zero)
 new_inner, _, _, count, nidx, wa = out
 check("sync: replicas equal after restart",
@@ -124,22 +123,20 @@ check("sync: replicas equal after restart",
                            - jax.tree.leaves(new_inner)[0][1])) == 0))
 check("sync: window count advanced", int(count) == 1)
 
-# plain train step lowers+runs too. fsdp and sequence_parallel are
-# exercised separately: enabling BOTH on the (2,4) host-device mesh
-# segfaults XLA 0.4.37's CPU SPMD partitioner at compile time (involuntary
-# full-remat path) — a backend bug, not a framework one; the combined
-# config compiles fine in the 256-chip dry-run meshes.
+# plain train step lowers+runs too: fsdp, sequence_parallel, and both
 shape2 = InputShape("tiny2", seq_len=16, global_batch=4, kind="train")
 specs2, dims2 = input_specs(cfg_lm, shape2)
 opt2 = mk_sgd(momentum=0.9, weight_decay=5e-4)
 os2 = opt2.init(params)
 batch2 = {"tokens": batch["tokens"][0, :4], "targets": batch["targets"][0, :4]}
 for label, kw in [("fsdp", dict(fsdp=True)),
-                  ("seq-parallel", dict(sequence_parallel=True))]:
+                  ("seq-parallel", dict(sequence_parallel=True)),
+                  ("fsdp+seq-parallel", dict(fsdp=True,
+                                             sequence_parallel=True))]:
     rules2 = make_tp_rules(mesh, **kw)
     b2 = make_train_step(lm, rules2, specs2, dims2, optimizer="sgd")
     c2 = b2.lower(mesh).compile()
-    with use_mesh(mesh):
+    with mesh:
         # fresh copies: the step donates params + opt state
         p2, o2, m2 = c2(jax.tree.map(jnp.array, params),
                         jax.tree.map(jnp.array, os2), batch2)

@@ -108,3 +108,23 @@ def test_unreadable_bench_fails(bench_check, tmp_path):
     rc = bench_check.run(str(p), _write(tmp_path, "t.json", _TH),
                          log=lambda *_: None)
     assert rc == 1
+
+
+def test_failed_worker_fails_the_run(tmp_path):
+    """A mesh-benchmark worker that dies fails the benchmark run (it used
+    to print an ERROR row and return {}, so the run exited 0)."""
+    root = os.path.dirname(_TOOLS)
+    sys.path.insert(0, root)
+    try:
+        from benchmarks.common import run_forced_device_worker
+    finally:
+        sys.path.remove(root)
+    worker = tmp_path / "worker.py"
+    worker.write_text("import os, sys\n"
+                      "assert os.environ['JAX_PLATFORMS'] == 'cpu'\n"
+                      "sys.exit(3)\n")
+    rows = []
+    with pytest.raises(RuntimeError, match="exited 3"):
+        run_forced_device_worker(str(worker), "--work", error_row="x/ERROR",
+                                 print_fn=rows.append, timeout=60)
+    assert rows and rows[0].startswith("x/ERROR,")

@@ -66,3 +66,19 @@ def test_image_dataset_label_noise_and_shapes():
                                       label_noise=0.2, seed=0)
     assert ds.train_inputs.shape == (64, 8, 8, 3)
     assert int(ds.train_targets.max()) < 4
+
+
+def test_markov_large_vocab_rows_are_sparse():
+    """Above MARKOV_FANOUT tokens the chain draws each row's successors
+    from a fixed support, so it builds in O(vocab) memory."""
+    from repro.data.synthetic import MARKOV_FANOUT
+    vocab = 4 * MARKOV_FANOUT
+    ds = make_markov_lm_dataset(vocab=vocab, seq_len=64, n_train=256,
+                                n_test=16, seed=0)
+    x = np.asarray(ds.train_inputs).reshape(-1)
+    y = np.asarray(ds.train_targets).reshape(-1)
+    assert 0 <= x.min() and x.max() < vocab
+    succ = {}
+    for a, b in zip(x, y):
+        succ.setdefault(int(a), set()).add(int(b))
+    assert max(len(s) for s in succ.values()) <= MARKOV_FANOUT

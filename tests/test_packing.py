@@ -825,28 +825,6 @@ def test_weight_publisher_repack_is_bit_exact():
     assert pub.n_published == 1
 
 
-# ------------------------------------------------------------------ TPU
-
-
-@pytest.mark.tpu
-@pytest.mark.skipif(jax.default_backend() != "tpu",
-                    reason="compiled (non-interpret) Pallas needs a TPU")
-def test_packed_kernels_compiled_on_tpu():
-    from repro.kernels.wa_update import wa_sync_fused_2d, wa_window_update_2d
-    tree = ragged_tree()
-    spec = pack_spec(tree)
-    new = pack(tree, spec)
-    ring = jnp.zeros((2, spec.padded // 1024, 1024))
-    total = jnp.zeros((spec.padded // 1024, 1024))
-    got = wa_window_update_2d(ring, total, new.reshape(total.shape),
-                              jnp.int32(0), jnp.float32(0.0),
-                              jnp.float32(1.0), interpret=False)
-    want = kref.wa_window_update_ref(ring, total, new.reshape(total.shape),
-                                     0, 0.0, 1.0)
-    for a, b in zip(got, want):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
 # ----------------------------------------- compressed WA precision (PR 10)
 
 

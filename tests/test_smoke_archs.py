@@ -83,3 +83,17 @@ def test_full_config_matches_assignment(arch):
            cfg.vocab_size, cfg.n_experts, cfg.top_k)
     assert got == expected
     assert cfg.source
+
+
+def test_full_preset_cuts_only_depth():
+    """``--preset full --n-layers N``: published widths, depth cut, the
+    cut reported in ``reduced``."""
+    from repro.configs import get_preset
+    full = get_config("granite-3-2b")
+    cfg, reduced = get_preset("granite-3-2b", "full", 2)
+    assert cfg == full.with_(n_layers=2)
+    assert reduced == {"n_layers": (full.n_layers, 2)}
+    assert get_preset("granite-3-2b", "full") == (full, {})
+    assert get_preset("granite-3-2b")[1] is None
+    with pytest.raises(ValueError):
+        get_preset("granite-3-2b", "full", full.n_layers + 1)

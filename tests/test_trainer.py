@@ -49,3 +49,17 @@ def test_best_tracking():
     out = make("hwa").run()
     assert out["best"]["test_acc"] >= max(
         h["test_acc"] for h in out["history"]) - 1e-9
+
+
+def test_on_step_sees_every_step_before_its_sync():
+    """``on_step`` runs after each inner step and before that step's sync
+    consumes the (donated) state."""
+    seen = []
+
+    def on_step(step, state, metrics):
+        seen.append((step, int(state.step), int(state.cycle)))
+
+    make("hwa", steps=16, H=8).run(on_step=on_step)
+    assert [s for s, _, _ in seen] == list(range(16))
+    assert [n for _, n, _ in seen] == list(range(1, 17))
+    assert [c for _, _, c in seen] == [0] * 8 + [1] * 8
