@@ -12,7 +12,8 @@ import argparse
 import jax
 import jax.numpy as jnp
 
-from repro.core import HWAConfig, hwa_init, hwa_inner_step, hwa_sync
+from repro.core import (HWAConfig, hwa_init, hwa_inner_step, hwa_sync,
+                        replica_divergence)
 from repro.core.bnstats import recompute_bn_stats
 from repro.data import make_prototype_image_dataset
 from repro.data.pipeline import replica_batch_indices
@@ -73,7 +74,8 @@ def main():
     for step in range(total_steps):
         state, loss = inner(state, step)
         if (step + 1) % steps_per_epoch == 0:
-            state, m = hwa_sync(hcfg, state)
+            div = replica_divergence(state.inner)   # before the restart
+            state, _ = hwa_sync(hcfg, state)
             wa = state.wa
             # Algorithm 2 line 3: recompute BN statistics under W̿
             bn = recompute_bn_stats(
@@ -84,7 +86,7 @@ def main():
             print(f"epoch {(step + 1) // steps_per_epoch}: "
                   f"train loss {float(loss):.4f}  "
                   f"W̿ test acc {float(acc):.4f}  "
-                  f"replica divergence {float(m['replica_divergence']):.3f}")
+                  f"replica divergence {float(div):.3f}")
 
 
 if __name__ == "__main__":

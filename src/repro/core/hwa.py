@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from repro.common.pytree import tree_mean_axis0
 from repro.core.offline import WindowState, window_init
 from repro.core.online import broadcast_to_replicas, online_average, \
-    online_average_named, replica_divergence
+    online_average_named
 from repro.optim.base import Optimizer, apply_updates
 
 PyTree = Any
@@ -229,7 +229,11 @@ def _sync_fused_c(cfg: HWAConfig, state: HWAState
 def hwa_sync(cfg: HWAConfig, state: HWAState) -> tuple[HWAState, PyTree]:
     """End-of-cycle sync (Algorithm 1 lines 8-12 + Algorithm 2).
 
-    Returns (new state, metrics). The window update is skipped on cycles
+    Returns (new state, metrics): the ``cycle`` counter, and ``k_alive``
+    on the resilient path. The replicas' divergence (paper Fig. 12) is
+    not computed here, as it reads every inner weight once more: call
+    :func:`repro.core.replica_divergence` on ``state.inner`` before the
+    sync where it is wanted. The window update is skipped on cycles
     not matching ``window_stride`` (sparse window, §III-B). On the kernel
     path with a dense f32 ring window the sync is one fused launch
     (:func:`_sync_fused`); otherwise mean and window update run as two
@@ -244,7 +248,6 @@ def hwa_sync(cfg: HWAConfig, state: HWAState) -> tuple[HWAState, PyTree]:
     kernels are bypassed (they cannot mask) and the alive count is
     reported as the ``k_alive`` metric.
     """
-    div = replica_divergence(state.inner)
     ws = state.window_state
     alive = None
     if cfg.resilient:
@@ -294,7 +297,7 @@ def hwa_sync(cfg: HWAConfig, state: HWAState) -> tuple[HWAState, PyTree]:
     new_state = HWAState(inner=inner, inner_opt=inner_opt,
                          window_state=window_state, wa=wa,
                          cycle=cycle, step=state.step)
-    metrics = {"replica_divergence": div, "cycle": cycle}
+    metrics = {"cycle": cycle}
     if alive is not None:
         metrics["k_alive"] = jnp.sum(alive.astype(jnp.int32))
     return new_state, metrics

@@ -490,7 +490,7 @@ def _make_hwa_sync_step(lm: LM, rules: ShardingRules, hwa_cfg: HWAConfig,
                                  health_axes=health_axes if resilient else (),
                                  health_scale=health_scale)
 
-        def local_step(*args):
+        def mesh_sync_step(*args):
             it = iter(args)
             inner, ring = next(it), next(it)
             scales = next(it) if has_scales else None
@@ -513,7 +513,7 @@ def _make_hwa_sync_step(lm: LM, rules: ShardingRules, hwa_cfg: HWAConfig,
         alive_spec = (P(_axes_entry(k_axes)),) if resilient else ()
         win_pspecs = tuple(p for _, _, p, _ in io)
         step = shard_map(
-            local_step, mesh,
+            mesh_sync_step, mesh,
             in_specs=(stacked_pspecs, *win_pspecs, P(), P()),
             out_specs=(stacked_pspecs, *win_pspecs, P(), P(), pspec_tree,
                        *alive_spec),
@@ -675,7 +675,7 @@ def _make_mesh_hwa_train_step(lm: LM, rules: ShardingRules, batch_specs,
         data_entry = (data_axes if len(data_axes) > 1
                       else (data_axes[0] if data_axes else None))
 
-        def local_step(inner, inner_opt, batch):
+        def mesh_train_step(inner, inner_opt, batch):
             params, opt_state = _squeeze0(inner), _squeeze0(inner_opt)
             (loss, _), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params, _squeeze0(batch))
@@ -690,7 +690,7 @@ def _make_mesh_hwa_train_step(lm: LM, rules: ShardingRules, batch_specs,
             lambda _: (P(rep_entry, data_entry) if data_entry is not None
                        else P(rep_entry)), kbatch_abs)
         step = shard_map(
-            local_step, mesh,
+            mesh_train_step, mesh,
             in_specs=(stacked_replica_specs(stacked_abs, rep_entry),
                       stacked_replica_specs(opt_abs, rep_entry),
                       batch_pspecs),
@@ -720,14 +720,14 @@ def _make_mesh_hwa_train_step(lm: LM, rules: ShardingRules, batch_specs,
                 notes="mesh-native HWA inner step, flash-pallas "
                       "attention (fully-manual, DP over data axes)"))
 
-    def local_step(inner, inner_opt, batch):
+    def mesh_train_step(inner, inner_opt, batch):
         params, opt_state, loss, _ = hwa_local_inner_step(
             _squeeze0(inner), _squeeze0(inner_opt), _squeeze0(batch),
             loss_fn, opt, lr)
         return _expand0(params), _expand0(opt_state), loss[None]
 
     step = shard_map(
-        local_step, mesh,
+        mesh_train_step, mesh,
         in_specs=(stacked_replica_specs(stacked_abs, rep_entry),
                   stacked_replica_specs(opt_abs, rep_entry),
                   stacked_replica_specs(kbatch_abs, rep_entry)),
@@ -936,7 +936,7 @@ def _make_mesh_hwa_sync_step(lm: LM, rules: ShardingRules,
                                  health_axes=health_axes if resilient else (),
                                  health_scale=health_scale)
 
-        def local_step(*args):
+        def mesh_sync_step(*args):
             it = iter(args)
             inner, ring = next(it), next(it)
             scales = next(it) if has_scales else None
@@ -959,7 +959,7 @@ def _make_mesh_hwa_sync_step(lm: LM, rules: ShardingRules,
         alive_spec = (P(_axes_entry(k_axes)),) if resilient else ()
         win_pspecs = tuple(p for _, _, p, _ in io)
         step = shard_map(
-            local_step, mesh,
+            mesh_sync_step, mesh,
             in_specs=(stacked_pspecs, *win_pspecs, P(), P(), P()),
             out_specs=(stacked_pspecs, *win_pspecs, P(), P(), pspec_tree,
                        P(), *alive_spec),
@@ -1078,10 +1078,13 @@ def _make_mesh_hwa_inner_sync_step(lm: LM, rules: ShardingRules,
                          "(mesh-resident only)")
     stacked_pspecs = rules.tree_specs(stacked_abs, stacked_dims)
     pod_size = K // topology.pods(mesh)
+    lspec, groups = spec.local_spec(), topology.inner_groups()
+
+    def mesh_inner_sync_step(inner):
+        return _local_inner_sync(lspec, pod_size, groups, inner)
+
     step = shard_map(
-        functools.partial(_local_inner_sync, spec.local_spec(), pod_size,
-                          topology.inner_groups()),
-        mesh,
+        mesh_inner_sync_step, mesh,
         in_specs=(stacked_pspecs,),
         out_specs=stacked_pspecs,
         check_rep=False)

@@ -37,7 +37,9 @@ from repro.configs import ARCH_IDS, PRESETS, get_preset
 from repro.core.hwa import HWAConfig
 from repro.data import DataPipeline, make_markov_lm_dataset
 from repro.models.registry import build_model
-from repro.train.trainer import TrainConfig, Trainer, lm_task
+from repro.train.trainer import (SPAN_BATCH, SPAN_CHECKPOINT,
+                                 SPAN_INNER_STEP, SPAN_LOSS_READ, SPAN_STEP,
+                                 SPAN_SYNC, TrainConfig, Trainer, lm_task)
 
 
 def add_preset_args(ap: argparse.ArgumentParser) -> None:
@@ -88,6 +90,7 @@ def run_mesh_native(args) -> dict:
     import jax
     import jax.numpy as jnp
     import numpy as np
+    from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
     from repro.common.compat import make_mesh
     from repro.common.quant import is_compressed, needs_scales
@@ -233,82 +236,94 @@ def run_mesh_native(args) -> dict:
             history = list(meta.get("history", []))
             print(f"[mesh-native] resumed from step {start_step} "
                   f"({session.step_dir(latest)})")
+    tokens = K * args.batch_size * args.seq_len
     with mesh:
         for step in range(start_step, args.steps):
-            if inject is not None and step == inject[0]:
-                from repro.resilience.faults import poison_replica
-                inner = jax.device_put(poison_replica(inner, inject[1]),
-                                       train.in_shardings[0])
-                print(f"[mesh-native] step {step}: injected NaN into "
-                      f"replica {inject[1]}")
-            ks = jax.random.split(jax.random.key(1000 + step), 2)
-            batch = {
-                "tokens": jax.random.randint(
-                    ks[0], (K, args.batch_size, args.seq_len), 0,
-                    cfg.vocab_size),
-                "targets": jax.random.randint(
-                    ks[1], (K, args.batch_size, args.seq_len), 0,
-                    cfg.vocab_size),
-            }
-            inner, inner_opt, losses = train_c(inner, inner_opt, batch)
-            # reduce on host: jnp.mean over the replica-sharded losses
-            # would launch a tiny all-reduce executable whose straggler
-            # groups keep holding collective threads after float() reads
-            # device 0's shard — the next dispatched step then deadlocks
-            # the CPU rendezvous pool. device_get drains every shard.
-            loss = float(np.mean(jax.device_get(losses)))
-            if (step + 1) % H == 0:
-                if inner_sync_c is not None and not topo.is_outer(sync_idx):
-                    # pod-internal restart: zero cross-pod traffic, no
-                    # window push (the window collects global W̄ only)
-                    inner = inner_sync_c(inner)
-                    history.append({"step": step + 1, "loss": loss,
-                                    "sync": "inner"})
-                    print(f"[mesh-native] step {step + 1} loss {loss:.4f} "
-                          f"inner sync (pods avg internally)")
-                else:
-                    # outputs mirror the inputs: (inner, <buffers...>,
-                    # count, next_idx, wa, cycle[, alive])
-                    res = sync_c(inner, *win)
-                    inner = res[0]
-                    count, nidx, wa, cycle = res[1 + n_buf:5 + n_buf]
-                    win = list(res[1:1 + n_buf]) + [count, nidx, cycle]
-                    if args.resilient:
-                        alive = res[5 + n_buf]
-                        k_alive = int(np.sum(jax.device_get(alive)))
-                        k_alive_min = min(k_alive_min, k_alive)
-                        if k_alive < K:
-                            # the sync already restarted the dead replica
-                            # from W̄; its stale momentum goes too
-                            from repro.resilience.health import \
-                                quarantine_opt_state
-                            inner_opt = jax.device_put(
-                                quarantine_opt_state(inner_opt, alive),
-                                train.in_shardings[1])
+            with StepTraceAnnotation(SPAN_STEP, step_num=step):
+                if inject is not None and step == inject[0]:
+                    from repro.resilience.faults import poison_replica
+                    inner = jax.device_put(poison_replica(inner, inject[1]),
+                                           train.in_shardings[0])
+                    print(f"[mesh-native] step {step}: injected NaN into "
+                          f"replica {inject[1]}")
+                with TraceAnnotation(SPAN_BATCH, step=step):
+                    ks = jax.random.split(jax.random.key(1000 + step), 2)
+                    batch = {
+                        "tokens": jax.random.randint(
+                            ks[0], (K, args.batch_size, args.seq_len), 0,
+                            cfg.vocab_size),
+                        "targets": jax.random.randint(
+                            ks[1], (K, args.batch_size, args.seq_len), 0,
+                            cfg.vocab_size),
+                    }
+                with TraceAnnotation(SPAN_INNER_STEP, step=step,
+                                     tokens=tokens):
+                    inner, inner_opt, losses = train_c(inner, inner_opt,
+                                                       batch)
+                # reduce on host: jnp.mean over the replica-sharded losses
+                # would launch a tiny all-reduce executable whose straggler
+                # groups keep holding collective threads after float() reads
+                # device 0's shard — the next dispatched step then deadlocks
+                # the CPU rendezvous pool. device_get drains every shard.
+                with TraceAnnotation(SPAN_LOSS_READ, step=step):
+                    loss = float(np.mean(jax.device_get(losses)))
+                if (step + 1) % H == 0:
+                    if inner_sync_c is not None and \
+                            not topo.is_outer(sync_idx):
+                        # pod-internal restart: zero cross-pod traffic, no
+                        # window push (the window collects global W̄ only)
+                        with TraceAnnotation(SPAN_SYNC, cycle=sync_idx + 1):
+                            inner = inner_sync_c(inner)
                         history.append({"step": step + 1, "loss": loss,
-                                        "sync": "outer",
-                                        "cycle": int(cycle),
-                                        "k_alive": k_alive})
-                        print(f"[mesh-native] step {step + 1} loss "
-                              f"{loss:.4f} cycle {int(cycle)} "
-                              f"k_alive {k_alive}/{K}")
+                                        "sync": "inner"})
+                        print(f"[mesh-native] step {step + 1} loss {loss:.4f} "
+                              f"inner sync (pods avg internally)")
                     else:
-                        history.append({"step": step + 1, "loss": loss,
-                                        "sync": "outer",
-                                        "cycle": int(cycle)})
-                        print(f"[mesh-native] step {step + 1} loss "
-                              f"{loss:.4f} cycle {int(cycle)} (K={K}, "
-                              f"mesh={dict(mesh.shape)})")
-                sync_idx += 1
-            if session is not None and \
-                    (step + 1) % args.checkpoint_every == 0:
-                session.save(
-                    step + 1,
-                    {"inner": inner, "inner_opt": inner_opt, "wa": wa},
-                    window=_window_like(win),
-                    meta={"step": step + 1, "cycle": int(cycle),
-                          "sync_idx": sync_idx, "loss": loss,
-                          "history": history})
+                        # outputs mirror the inputs: (inner, <buffers...>,
+                        # count, next_idx, wa, cycle[, alive])
+                        with TraceAnnotation(SPAN_SYNC, cycle=sync_idx + 1):
+                            res = sync_c(inner, *win)
+                        inner = res[0]
+                        count, nidx, wa, cycle = res[1 + n_buf:5 + n_buf]
+                        win = list(res[1:1 + n_buf]) + [count, nidx, cycle]
+                        if args.resilient:
+                            alive = res[5 + n_buf]
+                            k_alive = int(np.sum(jax.device_get(alive)))
+                            k_alive_min = min(k_alive_min, k_alive)
+                            if k_alive < K:
+                                # the sync already restarted the dead replica
+                                # from W̄; its stale momentum goes too
+                                from repro.resilience.health import \
+                                    quarantine_opt_state
+                                inner_opt = jax.device_put(
+                                    quarantine_opt_state(inner_opt, alive),
+                                    train.in_shardings[1])
+                            history.append({"step": step + 1, "loss": loss,
+                                            "sync": "outer",
+                                            "cycle": int(cycle),
+                                            "k_alive": k_alive})
+                            print(f"[mesh-native] step {step + 1} loss "
+                                  f"{loss:.4f} cycle {int(cycle)} "
+                                  f"k_alive {k_alive}/{K}")
+                        else:
+                            history.append({"step": step + 1, "loss": loss,
+                                            "sync": "outer",
+                                            "cycle": int(cycle)})
+                            print(f"[mesh-native] step {step + 1} loss "
+                                  f"{loss:.4f} cycle {int(cycle)} (K={K}, "
+                                  f"mesh={dict(mesh.shape)})")
+                    sync_idx += 1
+                if session is not None and \
+                        (step + 1) % args.checkpoint_every == 0:
+                    with TraceAnnotation(SPAN_CHECKPOINT, step=step):
+                        session.save(
+                            step + 1,
+                            {"inner": inner, "inner_opt": inner_opt,
+                             "wa": wa},
+                            window=_window_like(win),
+                            meta={"step": step + 1, "cycle": int(cycle),
+                                  "sync_idx": sync_idx, "loss": loss,
+                                  "history": history})
     wa_finite = all(bool(np.all(np.isfinite(jax.device_get(x))))
                     for x in jax.tree.leaves(wa)
                     if jnp.issubdtype(x.dtype, jnp.floating))

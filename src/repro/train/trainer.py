@@ -20,11 +20,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 import time
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.core.baselines import (ema_init, ema_update, lookahead_init,
                                   lookahead_update, sam_gradient, swa_init,
@@ -37,6 +39,20 @@ from repro.optim import (adamw, apply_updates, cosine_schedule, sgd,
                          step_decay_schedule, swa_constant_schedule)
 
 PyTree = Any
+
+# Profiler spans at the training loops' layer boundaries. They are
+# ``jax.profiler`` annotations: they land on the profiler's host plane, on
+# the clock its device events are mapped to, and record nothing when no
+# profiler is active. Every span of a step sits inside that step's
+# ``hwa.step`` (a StepTraceAnnotation, ``step_num``); the ids ride as
+# keyword arguments: ``step`` is the loop's step index, ``cycle`` the
+# syncs done with this one, ``tokens`` the input elements one step
+# consumes (K·B·S for a language model). ``hwa.batch`` and
+# ``hwa.loss_read`` are the mesh loop's (``launch/train.py``).
+SPANS = (SPAN_STEP, SPAN_INNER_STEP, SPAN_SYNC, SPAN_EVALUATE,
+         SPAN_CHECKPOINT, SPAN_CALLBACK, SPAN_BATCH, SPAN_LOSS_READ) = (
+    "hwa.step", "hwa.inner_step", "hwa.sync", "hwa.evaluate",
+    "hwa.checkpoint", "hwa.callback", "hwa.batch", "hwa.loss_read")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -182,7 +198,8 @@ class Trainer:
         ``on_step(step, state, metrics)`` is called after every inner
         step with the live :class:`HWAState`, before that step's sync
         (if any) consumes it. It runs synchronously, so a caller can time
-        steps or copy a pre-sync state out to check the sync.
+        steps or copy a pre-sync state out to check the sync. Under a
+        ``jax.profiler`` trace each step records the spans of :data:`SPANS`.
         """
         tc = self.tc
         key = jax.random.key(tc.seed)
@@ -190,6 +207,9 @@ class Trainer:
         history = []
         best = {"test_acc": -1.0, "test_loss": float("inf"), "step": 0}
         eval_every = tc.eval_every or self.sync_period
+        inputs = self.task.pipeline.dataset.train_inputs
+        step_tokens = self.task.pipeline.batch_size * math.prod(
+            inputs.shape[1:])
 
         def record(step, train_loss, eval_params, views=None):
             rec = {"step": step, "train_loss": float(train_loss)}
@@ -238,35 +258,47 @@ class Trainer:
                         print(f"[{self.task.name}/{tc.method}] resumed "
                               f"from step {start_step} "
                               f"({session.step_dir(start_step)})")
+            tokens = self.hwa_cfg.n_replicas * step_tokens
             for step in range(start_step, tc.total_steps):
-                state, metrics = self.hwa_step(state, step)
-                train_loss = metrics["loss"]
-                if on_step is not None:
-                    on_step(step, state, metrics)
-                if (step + 1) % self.sync_period == 0:
-                    views = None
-                    if eval_views:
-                        # snapshot BEFORE the sync resets inner <- outer
-                        views = {
-                            "inner": jax.tree.map(lambda x: x[0],
-                                                  state.inner),
-                            "outer": jax.tree.map(
-                                lambda x: jnp.mean(x, 0).astype(x.dtype),
-                                state.inner),
-                        }
-                    state, _ = self.sync_step(state)
-                    if ((step + 1) // self.sync_period) % max(
-                            eval_every // self.sync_period, 1) == 0:
-                        record(step + 1, train_loss, state.wa, views)
-                if session is not None and \
-                        (step + 1) % tc.checkpoint_every == 0:
-                    # HWAState is one registered-dataclass pytree (the
-                    # WindowState layout rides in its meta fields), so a
-                    # single named tree round-trips everything bit-exactly
-                    session.save(step + 1, {"hwa": state},
-                                 meta={"step": step + 1, "history": history,
-                                       "best": dict(best),
-                                       "train_loss": float(train_loss)})
+                with StepTraceAnnotation(SPAN_STEP, step_num=step):
+                    with TraceAnnotation(SPAN_INNER_STEP, step=step,
+                                         tokens=tokens):
+                        state, metrics = self.hwa_step(state, step)
+                    train_loss = metrics["loss"]
+                    if on_step is not None:
+                        with TraceAnnotation(SPAN_CALLBACK, step=step):
+                            on_step(step, state, metrics)
+                    if (step + 1) % self.sync_period == 0:
+                        views = None
+                        if eval_views:
+                            # snapshot BEFORE the sync resets inner <- outer
+                            views = {
+                                "inner": jax.tree.map(lambda x: x[0],
+                                                      state.inner),
+                                "outer": jax.tree.map(
+                                    lambda x: jnp.mean(x, 0).astype(x.dtype),
+                                    state.inner),
+                            }
+                        cycle = (step + 1) // self.sync_period
+                        with TraceAnnotation(SPAN_SYNC, cycle=cycle):
+                            state, _ = self.sync_step(state)
+                        if cycle % max(eval_every // self.sync_period,
+                                       1) == 0:
+                            with TraceAnnotation(SPAN_EVALUATE, step=step):
+                                record(step + 1, train_loss, state.wa, views)
+                    if session is not None and \
+                            (step + 1) % tc.checkpoint_every == 0:
+                        # HWAState is one registered-dataclass pytree (the
+                        # WindowState layout rides in its meta fields), so
+                        # a single named tree round-trips everything
+                        # bit-exactly
+                        with TraceAnnotation(SPAN_CHECKPOINT, step=step):
+                            session.save(step + 1, {"hwa": state},
+                                         meta={"step": step + 1,
+                                               "history": history,
+                                               "best": dict(best),
+                                               "train_loss":
+                                                   float(train_loss)})
             final_params = state.wa
         else:
             opt_state = self.optimizer.init(params)
@@ -280,24 +312,30 @@ class Trainer:
             swa_period = self.task.pipeline.steps_per_epoch
             train_loss = jnp.zeros(())
             for step in range(tc.total_steps):
-                params, opt_state, train_loss, _ = self._single_step(
-                    params, opt_state, step)
-                if tc.method == "ema":
-                    ema_state = self._ema_update(ema_state, params)
-                if tc.method == "lookahead" and (step + 1) % tc.lookahead_k == 0:
-                    la_state, params = self._lookahead_update(la_state, params)
-                if (tc.method == "swa" and step + 1 > swa_start
-                        and (step + 1) % swa_period == 0):
-                    swa_state = self._swa_update(swa_state, params)
-                if (step + 1) % eval_every == 0:
-                    eval_params = params
-                    if tc.method == "swa" and int(swa_state.n) > 0:
-                        eval_params = swa_params(swa_state, params)
-                    elif tc.method == "ema":
-                        eval_params = jax.tree.map(
-                            lambda a, p: a.astype(p.dtype),
-                            ema_state.avg, params)
-                    record(step + 1, train_loss, eval_params)
+                with StepTraceAnnotation(SPAN_STEP, step_num=step):
+                    with TraceAnnotation(SPAN_INNER_STEP, step=step,
+                                         tokens=step_tokens):
+                        params, opt_state, train_loss, _ = \
+                            self._single_step(params, opt_state, step)
+                    if tc.method == "ema":
+                        ema_state = self._ema_update(ema_state, params)
+                    if tc.method == "lookahead" and \
+                            (step + 1) % tc.lookahead_k == 0:
+                        la_state, params = self._lookahead_update(la_state,
+                                                                  params)
+                    if (tc.method == "swa" and step + 1 > swa_start
+                            and (step + 1) % swa_period == 0):
+                        swa_state = self._swa_update(swa_state, params)
+                    if (step + 1) % eval_every == 0:
+                        eval_params = params
+                        if tc.method == "swa" and int(swa_state.n) > 0:
+                            eval_params = swa_params(swa_state, params)
+                        elif tc.method == "ema":
+                            eval_params = jax.tree.map(
+                                lambda a, p: a.astype(p.dtype),
+                                ema_state.avg, params)
+                        with TraceAnnotation(SPAN_EVALUATE, step=step):
+                            record(step + 1, train_loss, eval_params)
             final_params = params
             if tc.method == "swa" and int(swa_state.n) > 0:
                 final_params = swa_params(swa_state, params)

@@ -534,6 +534,12 @@ check("two-level outer sync: audit outer_sync_ok "
 # ... and the INNER sync crosses ONLY the inner (per-pod) groups
 inner_b = tree_bundles.inner_sync
 inner_c = inner_b.lower(mesh_t).compile()
+# a profiler trace tells the programs apart by these names
+names = [c.as_text().split(",", 1)[0].split()[-1]
+         for c in (mesh_train_c, sync_c, inner_c)]
+check(f"mesh programs are named apart: {names}",
+      names == ["jit_mesh_train_step", "jit_mesh_sync_step",
+                "jit_mesh_inner_sync_step"])
 with mesh_t:
     i_inner = inner_c(jax.tree.map(jnp.array, div4_host))
 audit_inner = sync_collective_audit(inner_c.as_text(), mesh_t,

@@ -7,7 +7,8 @@ import pytest
 from repro.common.pytree import tree_mean_axis0, tree_stack
 from repro.core import (HWAConfig, hwa_init, hwa_inner_step, hwa_sync,
                         broadcast_to_replicas, online_average,
-                        window_init, window_update, window_average)
+                        replica_divergence, window_init, window_update,
+                        window_average)
 from repro.optim import sgd
 
 
@@ -103,8 +104,9 @@ def test_sync_restart_effect_and_divergence_metric():
         state, _ = hwa_inner_step(cfg, state, kbatch, quad_loss, opt, 0.05)
     w = state.inner["w"]
     assert float(jnp.max(jnp.abs(w[0] - w[1]))) > 1e-6
+    assert float(replica_divergence(state.inner)) > 0
     state, metrics = hwa_sync(cfg, state)
-    assert float(metrics["replica_divergence"]) > 0
+    assert "replica_divergence" not in metrics
     w = state.inner["w"]
     assert float(jnp.max(jnp.abs(w[0] - w[1]))) == 0.0
     assert int(state.cycle) == 1

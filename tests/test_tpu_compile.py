@@ -10,6 +10,7 @@ so every pytest-xdist worker collects the same tests and only the worker
 running this file loads the TPU library.
 """
 import os
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +24,8 @@ from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.paged_attention import paged_attention_pallas
 from repro.kernels.wa_update import wa_sync_fused_2d
 from repro.models.registry import build_model
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 GRANITE = get_config("granite-3-2b").with_(n_layers=1)
 HQ, HKV = GRANITE.n_heads, GRANITE.n_kv_heads
@@ -163,3 +166,26 @@ def test_paged_decode_compiles(one_chip):
         _sds((n_pages, ps, HKV, 64), jnp.bfloat16, one_chip),
         _sds((B, tw), jnp.int32, one_chip), _sds((B,), jnp.int32, one_chip))
     assert _n_kernels(c) == 1
+
+
+def test_trace_readers_find_the_kernels_by_name(one_chip, packed_len,
+                                                compiled_kernels):
+    """The chip benchmark's readers find each kernel in a compiled
+    program by its function name in the Mosaic body
+    (``chipbench.trace.pallas_kernels``): flash forward, dq and dkv in
+    the train step, the fused WA sync in the sync."""
+    sys.path.insert(0, ROOT)
+    from chipbench import trace as tr
+
+    def loss(q, k, v):
+        return jnp.sum(_flash(q, k, v).astype(jnp.float32))
+    flash = _compile(jax.grad(loss, argnums=(0, 1, 2)), *_qkv(one_chip))
+    ring, total, idx, full, inv, f32 = _window_args(one_chip, packed_len)
+    sync = _compile(ops.hwa_sync_packed.__wrapped__, f32((K, packed_len)),
+                    ring, total, idx, full, inv, donate_argnums=(1, 2))
+    for compiled, kernels in (
+            (flash, ("_flash_kernel", "_dq_kernel", "_dkv_kernel")),
+            (sync, ("_wa_sync_fused_kernel",))):
+        found = tr.pallas_kernels(compiled.as_text(), kernels)
+        assert {k: len(v) for k, v in found.items()} == \
+            {k: 1 for k in kernels}
