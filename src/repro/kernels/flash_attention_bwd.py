@@ -11,19 +11,22 @@ p = exp(softcap(S) − lse) and the score gradient as
 
 Two launches (ARCHITECTURE.md §7 has the tiling diagram):
 
-  dq sweep    grid (B, Hq, nq, nk), k innermost ("arbitrary") — the dq
-              accumulator for one q block lives in VMEM across the k
-              sweep: dq_i = Σ_j dS_ij · K_j. GQA indexes K/V at h // G.
+  dq sweep    grid (B, Hkv, nq, nk), k innermost ("arbitrary") — the
+              dq accumulators of one q block's G query heads live in
+              VMEM across the k sweep: dq_ig = Σ_j dS_ijg · K_j, one K/V
+              block fetched per step for the whole group.
   dk/dv sweep grid (B, Hkv, nk, nq), q innermost — dk/dv accumulators
               for one KV block live in VMEM across the q sweep, and the
               G query heads of the group accumulate into their shared
-              kv head inside the block (q/dO arrive as (block_q, G, D)
-              slabs): dv_j = Σ_i Σ_g p_ijᵀ·dO_ig, dk_j = Σ_i Σ_g dS_ijᵀ·Q_ig.
+              kv head inside the block:
+              dv_j = Σ_i Σ_g p_ijgᵀ·dO_ig, dk_j = Σ_i Σ_g dS_ijgᵀ·Q_ig.
 
-Both sweeps reuse the forward's block-skip predicate, so causal /
-sliding-window bands skip dead blocks entirely. Fully-masked rows carry
-lse == NEG_INF and zero dO·O, so every gradient contribution is
-re-masked to exactly zero (no NaN from the −1e30 fill).
+In both, q/dO arrive as (block_q, G·D) slabs, head g a static lane
+slice. Both sweeps reuse the forward's block-skip predicate, so causal /
+sliding-window bands skip dead blocks entirely, and clamp the index map
+of the swept operand onto the live band, so a dead step copies nothing.
+Fully-masked rows carry lse == NEG_INF and zero dO·O, so every gradient
+contribution is re-masked to exactly zero (no NaN from the −1e30 fill).
 """
 from __future__ import annotations
 
@@ -35,7 +38,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.flash_attention import (NEG_INF, band_mask, block_live,
-                                            heads_flat, row_to_col)
+                                            heads_flat, kv_block_index,
+                                            q_block_index, row_to_col)
 
 
 def _block_p_ds(q, kb, vb, do, lse, delta, q_start, k_start, *,
@@ -65,8 +69,9 @@ def _block_p_ds(q, kb, vb, do, lse, delta, q_start, k_start, *,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               acc_scr, *, block_q: int, block_k: int, causal: bool,
-               window: int | None, logit_softcap: float, dscale: float):
+               acc_scr, lse_scr, delta_scr, *, block_q: int, block_k: int,
+               group: int, causal: bool, window: int | None,
+               logit_softcap: float, dscale: float):
     i = pl.program_id(2)               # q block (parallel)
     j = pl.program_id(3)               # k block (innermost, sequential)
     nk = pl.num_programs(3)
@@ -74,25 +79,31 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     @pl.when(j == 0)
     def _init():
         acc_scr[...] = jnp.zeros_like(acc_scr)
+        # the q block's lse/Δ rows as columns, once for the whole k sweep
+        for g in range(group):
+            lse_scr[g] = row_to_col(lse_ref[0, g])         # (bq, 1)
+            delta_scr[g] = row_to_col(delta_ref[0, g])
 
     q_start = i * block_q
     k_start = j * block_k
 
     @pl.when(block_live(q_start, k_start, block_q, block_k, causal, window))
     def _compute():
-        q = q_ref[0].astype(jnp.float32)                   # (bq, d)
         kb = k_ref[0].astype(jnp.float32)                  # (bk, d)
         vb = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = row_to_col(lse_ref[0, 0])                    # (bq, 1)
-        delta = row_to_col(delta_ref[0, 0])
-        _, ds = _block_p_ds(
-            q, kb, vb, do, lse, delta, q_start, k_start,
-            block_q=block_q, block_k=block_k, causal=causal, window=window,
-            logit_softcap=logit_softcap, dscale=dscale)
-        acc_scr[...] += jax.lax.dot_general(
-            ds, kb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        d = kb.shape[1]
+        # the G query heads sharing this kv head, as in _dkv_kernel
+        for g in range(group):
+            hd = slice(g * d, (g + 1) * d)
+            q = q_ref[0, :, hd].astype(jnp.float32)        # (bq, d)
+            do = do_ref[0, :, hd].astype(jnp.float32)
+            _, ds = _block_p_ds(
+                q, kb, vb, do, lse_scr[g], delta_scr[g], q_start, k_start,
+                block_q=block_q, block_k=block_k, causal=causal,
+                window=window, logit_softcap=logit_softcap, dscale=dscale)
+            acc_scr[:, hd] += jax.lax.dot_general(
+                ds, kb, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
     @pl.when(j == nk - 1)
     def _finalize():
@@ -161,41 +172,55 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, dout, *, causal: bool,
                        out.astype(jnp.float32))[:, :, None, :]
     qf, kf, vf, dof = (heads_flat(x) for x in (q, k, v, dout))
 
-    common = dict(block_q=block_q, block_k=block_k, causal=causal,
-                  window=window, logit_softcap=logit_softcap, dscale=dscale)
+    band = dict(block_q=block_q, block_k=block_k, causal=causal,
+                window=window)
+    common = dict(group=G, logit_softcap=logit_softcap, dscale=dscale,
+                  **band)
 
+    # q/dO/lse/Δ arrive as whole GQA groups in both sweeps: a G·D lane
+    # block (or G rows of lse/Δ) at head-block index h covers query heads
+    # [h·G, (h+1)·G), the kv head h's group. Dead steps repeat a live
+    # step's block index (kv_block_index, q_block_index): no copy.
+    kv = lambda b, h, i, j: (b, kv_block_index(i, j, nk=nk, **band), h)
+    slab = lambda b, h, i, j: (b, i, h)
+    rows = lambda b, h, i, j: (b, h, 0, i)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, **common),
-        grid=(B, Hq, nq, nk),
+        grid=(B, Hkv, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, h, i, j: (b, i, h)),
-            pl.BlockSpec((1, block_k, D), lambda b, h, i, j: (b, j, h // G)),
-            pl.BlockSpec((1, block_k, D), lambda b, h, i, j: (b, j, h // G)),
-            pl.BlockSpec((1, block_q, D), lambda b, h, i, j: (b, i, h)),
-            pl.BlockSpec((1, 1, 1, block_q), lambda b, h, i, j: (b, h, 0, i)),
-            pl.BlockSpec((1, 1, 1, block_q), lambda b, h, i, j: (b, h, 0, i)),
+            pl.BlockSpec((1, block_q, G * D), slab),
+            pl.BlockSpec((1, block_k, D), kv),
+            pl.BlockSpec((1, block_k, D), kv),
+            pl.BlockSpec((1, block_q, G * D), slab),
+            pl.BlockSpec((1, G, 1, block_q), rows),
+            pl.BlockSpec((1, G, 1, block_q), rows),
         ],
-        out_specs=pl.BlockSpec((1, block_q, D), lambda b, h, i, j: (b, i, h)),
+        out_specs=pl.BlockSpec((1, block_q, G * D), slab),
         out_shape=jax.ShapeDtypeStruct((B, S, Hq * D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((block_q, G * D), jnp.float32),
+            pltpu.VMEM((G, block_q, 1), jnp.float32),
+            pltpu.VMEM((G, block_q, 1), jnp.float32),
+        ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf, dof, lse, delta)
 
-    # q/dO/lse/Δ arrive as whole GQA groups: a G·D lane block (or G rows
-    # of lse/Δ) at head-block index h covers query heads [h·G, (h+1)·G)
+    qi = lambda j, i: q_block_index(j, i, nq=nq, **band)
+    slab_t = lambda b, h, j, i: (b, qi(j, i), h)
+    rows_t = lambda b, h, j, i: (b, h, 0, qi(j, i))
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, group=G, **common),
+        functools.partial(_dkv_kernel, **common),
         grid=(B, Hkv, nk, nq),
         in_specs=[
-            pl.BlockSpec((1, block_q, G * D), lambda b, h, j, i: (b, i, h)),
+            pl.BlockSpec((1, block_q, G * D), slab_t),
             pl.BlockSpec((1, block_k, D), lambda b, h, j, i: (b, j, h)),
             pl.BlockSpec((1, block_k, D), lambda b, h, j, i: (b, j, h)),
-            pl.BlockSpec((1, block_q, G * D), lambda b, h, j, i: (b, i, h)),
-            pl.BlockSpec((1, G, 1, block_q), lambda b, h, j, i: (b, h, 0, i)),
-            pl.BlockSpec((1, G, 1, block_q), lambda b, h, j, i: (b, h, 0, i)),
+            pl.BlockSpec((1, block_q, G * D), slab_t),
+            pl.BlockSpec((1, G, 1, block_q), rows_t),
+            pl.BlockSpec((1, G, 1, block_q), rows_t),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda b, h, j, i: (b, j, h)),

@@ -22,7 +22,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.common.packing import ALIGN
-from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.flash_attention import (flash_attention_pallas,
+                                           resolve_blocks)
 from repro.kernels.wa_update import (TILE_COLS, TILE_ROWS, online_mean_2d,
                                      wa_sync_fused_2d, wa_sync_fused_c_2d,
                                      wa_window_update_2d,
@@ -175,11 +176,13 @@ def online_mean(stacked):
 
 
 def flash_attention(q, k, v, q_pos=None, k_pos=None, *, window=None,
-                    logit_softcap=0.0, block_q=128, block_k=128):
+                    logit_softcap=0.0, block_q=None, block_k=None):
     """run_attention-compatible wrapper (training/prefill layout:
     contiguous positions starting at 0). Pads head_dim to 128 and ragged
     sequence lengths up to a block multiple; differentiable end-to-end
-    (the kernel's custom VJP composes with the pad/slice here).
+    (the kernel's custom VJP composes with the pad/slice here). Blocks
+    left as None follow from the shapes (``flash_blocks``); they tile
+    the lengths padded to a multiple of 128, no further.
 
     Padding is grad-exact: padded key positions sit ABOVE every real
     query position, so the causal mask hides them; padded query rows are
@@ -194,7 +197,8 @@ def flash_attention(q, k, v, q_pos=None, k_pos=None, *, window=None,
         q = jnp.pad(q, padw)
         k = jnp.pad(k, padw)
         v = jnp.pad(v, padw)
-    bq, bk = min(block_q, S), min(block_k, T)
+    bq, bk = resolve_blocks(block_q, block_k, S, T, q.shape[-1],
+                            q.shape[2] // k.shape[2], window)
     pad_s, pad_t = (-S) % bq, (-T) % bk
     seqpad = lambda x, n: jnp.pad(x, ((0, 0), (0, n), (0, 0), (0, 0)))
     if pad_s:

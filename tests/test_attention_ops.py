@@ -30,7 +30,9 @@ SMOKE = os.environ.get("REPRO_ATTN_SMOKE") == "1"
 B = 2
 
 
-def _case(S, Hq, Hkv, D, window, cap, dtype, smoke=False):
+def _case(S, Hq, Hkv, D, window, cap, dtype, smoke=False, blocks=(64, 64)):
+    """``blocks`` None leaves the blocks to the shapes (``flash_blocks``):
+    a KV head's whole GQA group per grid step, blocks of up to 1024."""
     marks = []
     if not smoke:
         marks.append(pytest.mark.slow)
@@ -38,8 +40,9 @@ def _case(S, Hq, Hkv, D, window, cap, dtype, smoke=False):
             marks.append(pytest.mark.skip(
                 reason="REPRO_ATTN_SMOKE=1: PR-lane smoke subset only"))
     return pytest.param(
-        S, Hq, Hkv, D, window, cap, dtype, marks=marks,
-        id=f"S{S}-H{Hq}kv{Hkv}-D{D}-w{window}-cap{cap}-{dtype}")
+        S, Hq, Hkv, D, window, cap, dtype, blocks, marks=marks,
+        id=f"S{S}-H{Hq}kv{Hkv}-D{D}-w{window}-cap{cap}-{dtype}"
+        + ("" if blocks else "-auto"))
 
 
 # One axis varies per row (plus a kitchen-sink case); smoke rows cover
@@ -64,9 +67,19 @@ MATRIX = [
     # bf16 inputs, f32 tolerances
     _case(128, 4, 2, 64, None, 0.0, "bfloat16", smoke=True),
     _case(128, 4, 4, 64, 32, 15.0, "bfloat16"),
+    # blocks from the shapes, the GQA group per grid step: G = 2, 4, 8;
+    # S = 512 and 1024 (blocks above 128); ragged 900 → 1024 with 256-row
+    # q blocks; a window under the largest block; softcap; bf16
+    _case(512, 4, 2, 64, None, 0.0, "float32", blocks=None),
+    _case(1024, 8, 2, 64, None, 0.0, "float32", blocks=None),
+    _case(1024, 8, 1, 64, None, 0.0, "float32", blocks=None),
+    _case(900, 8, 2, 64, None, 0.0, "float32", blocks=None),
+    _case(1024, 8, 2, 64, 200, 0.0, "float32", blocks=None),
+    _case(512, 8, 2, 64, None, 15.0, "float32", blocks=None),
+    _case(1024, 8, 2, 64, 300, 15.0, "bfloat16", blocks=None),
 ]
 
-MATRIX_ARGS = "S,Hq,Hkv,D,window,cap,dtype"
+MATRIX_ARGS = "S,Hq,Hkv,D,window,cap,dtype,blocks"
 
 
 def _mk(S, Hq, Hkv, D, dtype, seed=0):
@@ -93,10 +106,11 @@ def _tols(dtype):
 
 
 @pytest.mark.parametrize(MATRIX_ARGS, MATRIX)
-def test_forward_matches_naive(S, Hq, Hkv, D, window, cap, dtype):
+def test_forward_matches_naive(S, Hq, Hkv, D, window, cap, dtype, blocks):
     q, k, v, _ = _mk(S, Hq, Hkv, D, dtype)
+    block_q, block_k = blocks or (None, None)
     out = kops.flash_attention(q, k, v, window=window, logit_softcap=cap,
-                               block_q=64, block_k=64)
+                               block_q=block_q, block_k=block_k)
     ref = _naive(q, k, v, window, cap)
     rtol, atol = _tols(dtype)
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -105,12 +119,13 @@ def test_forward_matches_naive(S, Hq, Hkv, D, window, cap, dtype):
 
 
 @pytest.mark.parametrize(MATRIX_ARGS, MATRIX)
-def test_grads_match_naive(S, Hq, Hkv, D, window, cap, dtype):
+def test_grads_match_naive(S, Hq, Hkv, D, window, cap, dtype, blocks):
     q, k, v, w = _mk(S, Hq, Hkv, D, dtype)
+    block_q, block_k = blocks or (None, None)
 
     def f_flash(q, k, v):
         out = kops.flash_attention(q, k, v, window=window, logit_softcap=cap,
-                                   block_q=64, block_k=64)
+                                   block_q=block_q, block_k=block_k)
         return jnp.sum(out.astype(jnp.float32) * w)
 
     def f_naive(q, k, v):

@@ -52,21 +52,43 @@ def test_online_mean_shapes_dtypes(k, shape, dtype, seed):
                                atol=1e-6)
 
 
-@pytest.mark.parametrize("B,S,Hq,Hkv,D,window,cap,dtype", [
-    (1, 128, 2, 1, 16, None, 0.0, "float32"),
-    (2, 128, 4, 2, 32, None, 50.0, "float32"),
-    (1, 256, 2, 2, 16, 64, 0.0, "float32"),
-    (1, 128, 4, 1, 8, 32, 30.0, "float32"),
-    (2, 128, 4, 4, 64, None, 0.0, "bfloat16"),
-    (1, 128, 8, 2, 24, None, 0.0, "float32"),   # head_dim padded to 128
+def _oracle_case(B, S, Hq, Hkv, D, window, cap, dtype, blocks=(64, 64)):
+    """64-row blocks as given by the caller, or None: the blocks follow
+    from the shapes (``flash_blocks``) and the grid takes a KV head's
+    whole GQA group per step."""
+    case_id = "-".join(str(x) for x in (B, S, Hq, Hkv, D, window, cap,
+                                        dtype))
+    return pytest.param(B, S, Hq, Hkv, D, window, cap, dtype, blocks,
+                        id=case_id if blocks else case_id + "-auto")
+
+
+@pytest.mark.parametrize("B,S,Hq,Hkv,D,window,cap,dtype,blocks", [
+    _oracle_case(1, 128, 2, 1, 16, None, 0.0, "float32"),
+    _oracle_case(2, 128, 4, 2, 32, None, 50.0, "float32"),
+    _oracle_case(1, 256, 2, 2, 16, 64, 0.0, "float32"),
+    _oracle_case(1, 128, 4, 1, 8, 32, 30.0, "float32"),
+    _oracle_case(2, 128, 4, 4, 64, None, 0.0, "bfloat16"),
+    _oracle_case(1, 128, 8, 2, 24, None, 0.0, "float32"),  # D padded to 128
+    # blocks from the shapes: GQA groups of 2, 4 and 8 per grid step
+    _oracle_case(1, 512, 4, 2, 64, None, 0.0, "float32", None),
+    _oracle_case(1, 1024, 8, 2, 64, None, 0.0, "float32", None),
+    _oracle_case(1, 1024, 8, 1, 64, None, 0.0, "float32", None),
+    # ragged: 900 rows pad to 1024, not a multiple of the 256-row q block
+    _oracle_case(1, 900, 8, 2, 64, None, 0.0, "float32", None),
+    # a window narrower than the largest block: block_k held to 256
+    _oracle_case(1, 1024, 8, 2, 64, 200, 0.0, "float32", None),
+    _oracle_case(1, 512, 8, 2, 64, None, 30.0, "float32", None),
+    _oracle_case(2, 1024, 8, 2, 64, 300, 20.0, "bfloat16", None),
 ])
-def test_flash_pallas_vs_oracle(B, S, Hq, Hkv, D, window, cap, dtype):
+def test_flash_pallas_vs_oracle(B, S, Hq, Hkv, D, window, cap, dtype,
+                                blocks):
     ks = jax.random.split(jax.random.key(0), 3)
     q = jax.random.normal(ks[0], (B, S, Hq, D)).astype(dtype)
     k = jax.random.normal(ks[1], (B, S, Hkv, D)).astype(dtype)
     v = jax.random.normal(ks[2], (B, S, Hkv, D)).astype(dtype)
+    block_q, block_k = blocks or (None, None)
     out = kops.flash_attention(q, k, v, window=window, logit_softcap=cap,
-                               block_q=64, block_k=64)
+                               block_q=block_q, block_k=block_k)
     ref = kref.attention_ref(q, k, v, window=window, logit_softcap=cap)
     tol = 2e-2 if dtype == "bfloat16" else 2e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),

@@ -153,6 +153,49 @@ def test_flash_backward_compiles(one_chip):
     assert _n_kernels(c) == 3
 
 
+@pytest.mark.parametrize("window", [None, 200])
+def test_flash_cell_shapes_compile(one_chip, compiled_kernels, window):
+    """granite-3-2b.k2-h4's attention as the train step calls it: K=2
+    vmapped replicas × 4 sequences of 1024, head_dim 64, through
+    ``ops.flash_attention`` with the blocks it picks (one KV head's GQA
+    group per grid step); and a window under the chosen block_k. Forward
+    and ``jax.grad`` compile, with the kernels the readers find by name,
+    once each: 1 forward, and 1 forward + dq + dkv."""
+    sys.path.insert(0, ROOT)
+    from chipbench import trace as tr
+    bf16 = lambda h: _sds((K, 4, 1024, h, 64), jnp.bfloat16, one_chip)
+    qkv = bf16(HQ), bf16(HKV), bf16(HKV)
+    attn = jax.vmap(lambda q, k, v: ops.flash_attention(q, k, v,
+                                                        window=window))
+
+    def loss(q, k, v):
+        return jnp.sum(attn(q, k, v).astype(jnp.float32))
+    names = ("_flash_kernel", "_dq_kernel", "_dkv_kernel")
+    for fn, n_fwd, n_bwd in ((attn, 1, 0),
+                             (jax.grad(loss, argnums=(0, 1, 2)), 1, 1)):
+        c = _compile(fn, *qkv)
+        found = tr.pallas_kernels(c.as_text(), names)
+        assert {k: len(v) for k, v in found.items()} == \
+            dict(zip(names, (n_fwd, n_bwd, n_bwd)))
+        assert _n_kernels(c) == n_fwd + 2 * n_bwd
+
+
+@pytest.mark.parametrize("G,D_pad", [(1, 128), (8, 128), (1, 256),
+                                     (4, 256), (8, 256)])
+def test_flash_chosen_blocks_compile(one_chip, G, D_pad):
+    """The blocks ``flash_blocks`` picks stay within Mosaic's scoped VMEM
+    for other group sizes and head dims, with f32 inputs (the widest
+    blocks): forward and ``jax.grad``, 2048 tokens."""
+    f32 = lambda h: _sds((1, SEQ, h, D_pad), jnp.float32, one_chip)
+    qkv = f32(2 * G), f32(2), f32(2)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention_pallas(q, k, v, causal=True,
+                                              interpret=False))
+    assert _n_kernels(_compile(jax.grad(loss, argnums=(0, 1, 2)),
+                               *qkv)) == 3
+
+
 def test_paged_decode_compiles(one_chip):
     """One decode token per sequence against a 16-token-page pool: 4
     sequences of up to 256 positions, head_dim 64 (padded in-wrapper)."""
