@@ -29,6 +29,7 @@ path.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 
@@ -72,6 +73,16 @@ def launcher_config(args):
     return cfg
 
 
+def mesh_batch(step, shape, vocab: int) -> dict:
+    """The mesh loop's batch for ``step``: uniform tokens and targets of
+    ``shape`` (K, B, S) from ``key(1000 + step)``, a stateless function of
+    the step index (so a resume repeats it)."""
+    import jax
+    ks = jax.random.split(jax.random.key(1000 + step), 2)
+    return {"tokens": jax.random.randint(ks[0], shape, 0, vocab),
+            "targets": jax.random.randint(ks[1], shape, 0, vocab)}
+
+
 def run_mesh_native(args, on_step=None, init=None) -> dict:
     """Train with the shard_map HWA steps on a (replica=K, data,
     model=--tp) mesh built from whatever devices are available — or, with
@@ -87,9 +98,18 @@ def run_mesh_native(args, on_step=None, init=None) -> dict:
     links under the tree), executed for real (one process, SPMD across
     the local devices).
 
-    ``on_step(step, live)``, when given, is called after every step's
-    loss read and before that step's sync (if any), like the Trainer's
-    ``on_step``. ``live`` holds the loop's arrays as they stand:
+    The host reads each step's losses one step behind: step n's once step
+    n+1, or the sync after step n, is queued behind them. Only reads that
+    must come before the next dispatch wait on the chips: ``--resilient``'s
+    ``alive`` (it decides the next step's optimizer state), a checkpoint's
+    ``meta`` and the last step's losses; the result's ``stalled_reads``
+    counts them. The outer-sync count is kept on the host and checked
+    against the sync program's ``cycle`` at those reads and at the end.
+
+    ``on_step(step, live)``, when given, is called once per step, after
+    the step is dispatched (and step − 1's losses are read) and before
+    that step's sync (if any), like the Trainer's ``on_step``. ``live``
+    holds the loop's arrays as they stand:
     ``inner``, ``inner_opt``, ``losses`` (K,), ``batch``, ``window`` (the
     sync's window arguments: ring, [scales], total, [comp], count,
     next_idx, cycle), ``wa``, and the ``pack_spec`` of the ring and the
@@ -214,6 +234,7 @@ def run_mesh_native(args, on_step=None, init=None) -> dict:
     loss = float("nan")
     history = []
     sync_idx = 0
+    cycles = 0              # outer syncs done: the sync program's ``cycle``
     start_step = 0
     k_alive_min = K
     if session is not None and args.resume:
@@ -241,13 +262,48 @@ def run_mesh_native(args, on_step=None, init=None) -> dict:
             win[n_buf], win[n_buf + 1] = ws.count, ws.next_idx
             meta = session.meta(latest)
             start_step = int(meta["step"])
-            cycle = win[-1] = jnp.asarray(meta["cycle"], jnp.int32)
+            cycles = int(meta["cycle"])
+            cycle = win[-1] = jnp.asarray(cycles, jnp.int32)
             sync_idx = int(meta["sync_idx"])
             loss = float(meta["loss"])
             history = list(meta.get("history", []))
             print(f"[mesh-native] resumed from step {start_step} "
                   f"({session.step_dir(latest)})")
     tokens = K * args.batch_size * args.seq_len
+    # each chip makes its own replica's rows, in the train program's batch
+    # sharding; the step is traced, so one program serves every step
+    make_batch = jax.jit(
+        functools.partial(mesh_batch, shape=(K, args.batch_size,
+                                             args.seq_len),
+                          vocab=cfg.vocab_size),
+        out_shardings=train.in_shardings[2])
+    pending = None          # (step, losses): dispatched, not yet read
+    stalled_reads = 0
+
+    def host_read(x, queued: bool):
+        """``x`` on the host; ``queued`` says whether a program is queued
+        behind it, so that the chips do not wait on this read."""
+        nonlocal stalled_reads
+        stalled_reads += not queued
+        return jax.device_get(x)
+
+    def read_loss(step: int, queued: bool) -> None:
+        # reduce on host: jnp.mean over the replica-sharded losses would
+        # launch a tiny all-reduce executable whose straggler groups keep
+        # holding collective threads after float() reads device 0's shard
+        # — the next dispatched step then deadlocks the CPU rendezvous
+        # pool. device_get drains every shard.
+        nonlocal loss, pending
+        of_step, losses = pending
+        with TraceAnnotation(SPAN_LOSS_READ, step=step, of_step=of_step):
+            loss = float(np.mean(host_read(losses, queued)))
+        pending = None
+
+    def check_cycles(device_cycle) -> None:
+        if int(device_cycle) != cycles:
+            raise RuntimeError(f"the sync program counts {int(device_cycle)}"
+                               f" outer syncs, the loop {cycles}")
+
     with mesh:
         for step in range(start_step, args.steps):
             with StepTraceAnnotation(SPAN_STEP, step_num=step):
@@ -258,26 +314,14 @@ def run_mesh_native(args, on_step=None, init=None) -> dict:
                     print(f"[mesh-native] step {step}: injected NaN into "
                           f"replica {inject[1]}")
                 with TraceAnnotation(SPAN_BATCH, step=step):
-                    ks = jax.random.split(jax.random.key(1000 + step), 2)
-                    batch = {
-                        "tokens": jax.random.randint(
-                            ks[0], (K, args.batch_size, args.seq_len), 0,
-                            cfg.vocab_size),
-                        "targets": jax.random.randint(
-                            ks[1], (K, args.batch_size, args.seq_len), 0,
-                            cfg.vocab_size),
-                    }
+                    batch = make_batch(step)
                 with TraceAnnotation(SPAN_INNER_STEP, step=step,
                                      tokens=tokens):
                     inner, inner_opt, losses = train_c(inner, inner_opt,
                                                        batch)
-                # reduce on host: jnp.mean over the replica-sharded losses
-                # would launch a tiny all-reduce executable whose straggler
-                # groups keep holding collective threads after float() reads
-                # device 0's shard — the next dispatched step then deadlocks
-                # the CPU rendezvous pool. device_get drains every shard.
-                with TraceAnnotation(SPAN_LOSS_READ, step=step):
-                    loss = float(np.mean(jax.device_get(losses)))
+                if pending is not None:      # step - 1's, this one queued
+                    read_loss(step, queued=True)
+                pending = (step, losses)
                 if on_step is not None:
                     with TraceAnnotation(SPAN_CALLBACK, step=step):
                         on_step(step, {
@@ -293,6 +337,7 @@ def run_mesh_native(args, on_step=None, init=None) -> dict:
                         # window push (the window collects global W̄ only)
                         with TraceAnnotation(SPAN_SYNC, cycle=sync_idx + 1):
                             inner = inner_sync_c(inner)
+                        read_loss(step, queued=True)
                         history.append({"step": step + 1, "loss": loss,
                                         "sync": "inner"})
                         print(f"[mesh-native] step {step + 1} loss {loss:.4f} "
@@ -305,9 +350,14 @@ def run_mesh_native(args, on_step=None, init=None) -> dict:
                         inner = res[0]
                         count, nidx, wa, cycle = res[1 + n_buf:5 + n_buf]
                         win = list(res[1:1 + n_buf]) + [count, nidx, cycle]
+                        read_loss(step, queued=True)
+                        cycles += 1
                         if args.resilient:
                             alive = res[5 + n_buf]
-                            k_alive = int(np.sum(jax.device_get(alive)))
+                            alive_h, cycle_h = host_read((alive, cycle),
+                                                         queued=False)
+                            check_cycles(cycle_h)
+                            k_alive = int(np.sum(alive_h))
                             k_alive_min = min(k_alive_min, k_alive)
                             if k_alive < K:
                                 # the sync already restarted the dead replica
@@ -319,38 +369,44 @@ def run_mesh_native(args, on_step=None, init=None) -> dict:
                                     train.in_shardings[1])
                             history.append({"step": step + 1, "loss": loss,
                                             "sync": "outer",
-                                            "cycle": int(cycle),
+                                            "cycle": cycles,
                                             "k_alive": k_alive})
                             print(f"[mesh-native] step {step + 1} loss "
-                                  f"{loss:.4f} cycle {int(cycle)} "
+                                  f"{loss:.4f} cycle {cycles} "
                                   f"k_alive {k_alive}/{K}")
                         else:
                             history.append({"step": step + 1, "loss": loss,
                                             "sync": "outer",
-                                            "cycle": int(cycle)})
+                                            "cycle": cycles})
                             print(f"[mesh-native] step {step + 1} loss "
-                                  f"{loss:.4f} cycle {int(cycle)} (K={K}, "
+                                  f"{loss:.4f} cycle {cycles} (K={K}, "
                                   f"mesh={dict(mesh.shape)})")
                     sync_idx += 1
-                if session is not None and \
-                        (step + 1) % args.checkpoint_every == 0:
+                save = session is not None and \
+                    (step + 1) % args.checkpoint_every == 0
+                if pending is not None and (save or step == args.steps - 1):
+                    read_loss(step, queued=False)
+                if save:
                     with TraceAnnotation(SPAN_CHECKPOINT, step=step):
+                        check_cycles(host_read(cycle, queued=False))
                         session.save(
                             step + 1,
                             {"inner": inner, "inner_opt": inner_opt,
                              "wa": wa},
                             window=_window_like(win),
-                            meta={"step": step + 1, "cycle": int(cycle),
+                            meta={"step": step + 1, "cycle": cycles,
                                   "sync_idx": sync_idx, "loss": loss,
                                   "history": history})
+    check_cycles(jax.device_get(cycle))
     wa_finite = all(bool(np.all(np.isfinite(jax.device_get(x))))
                     for x in jax.tree.leaves(wa)
                     if jnp.issubdtype(x.dtype, jnp.floating))
     ws_final = _window_like(win)
-    out = {"final_loss": loss, "cycles": int(cycle), "syncs": sync_idx,
+    out = {"final_loss": loss, "cycles": cycles, "syncs": sync_idx,
            "history": history, "sync_tree": args.sync_tree,
            "wa_dtype": plan.wa_dtype, "comms_dtype": plan.comms_dtype,
            "wa_finite": wa_finite, "k_alive_min": k_alive_min,
+           "stalled_reads": stalled_reads,
            "mesh": {k: int(v) for k, v in mesh.shape.items()},
            "_state": {"inner": inner, "wa": wa, "ring": ws_final.ring,
                       "total": ws_final.total}}
